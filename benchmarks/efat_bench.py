@@ -390,6 +390,9 @@ def main(argv=None) -> int:
             pop_ext, model_ext = _parse_mesh(args.mesh)
             need = max(need, pop_ext * model_ext)
         os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={need}"
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.mesh:
         report = run_mesh_bench(

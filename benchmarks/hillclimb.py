@@ -102,10 +102,10 @@ def run_variant(arch, shape, name, spec, out_dir):
 
 
 def describe(info):
-    # hardware terms come from the shared constants in repro.tune.roofline
-    from repro.tune.roofline import HBM_BW as HBM
-    from repro.tune.roofline import ICI_BW as ICI
-    from repro.tune.roofline import PEAK_FLOPS as PEAK
+    # the dry run targets a v5e pod: its published peaks (repro.tune.roofline)
+    from repro.tune.roofline import V5E
+
+    PEAK, HBM, ICI = V5E.flops, V5E.hbm_bw, V5E.ici_link_bw
 
     if info.get("status") != "ok":
         return f"FAILED: {info.get('error')}"

@@ -43,6 +43,7 @@ import numpy as np
 from repro.core import from_fault_map, healthy, random_fault_map
 from repro.core.masking import fault_linear
 from repro.kernels.common import dtype_tol, is_tpu_backend
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import attention_impl
 
 Row = tuple[str, float, str]
@@ -216,19 +217,23 @@ def bench_kernel_regressions() -> list[Row]:
 ALL = [bench_masked_matmul_overhead, bench_attention_impls, bench_kernel_regressions]
 
 
-def print_rows(fns) -> None:
+def print_rows(fns) -> int:
     """Shared ``name,us_per_call,derived`` CSV printer (also used by
-    benchmarks/run.py so the two outputs cannot drift)."""
+    benchmarks/run.py so the two outputs cannot drift). A phase that raises
+    prints its traceback and no row; returns how many phases failed."""
     import traceback
 
     print("name,us_per_call,derived")
+    failed = 0
     for fn in fns:
         try:
             for name, us, derived in fn():
                 print(f'{name},{us:.1f},"{derived}"', flush=True)
-        except Exception as e:
+        except Exception:
             traceback.print_exc()
-            print(f'{fn.__name__},-1,"ERROR: {e}"', flush=True)
+            print(f"FAIL: {fn.__name__}", file=sys.stderr, flush=True)
+            failed += 1
+    return failed
 
 
 # ---------------------------------------------------------------------------
@@ -359,17 +364,17 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--max-evals", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if not args.tune:
-        print_rows(ALL)
-        return 0
+        return 1 if print_rows(ALL) else 0
 
     snapshot, results, cache = run_tune(iters=args.iters, max_evals=args.max_evals)
     for res in results:
         print(
             f"{res.kernel:18s} {res.heuristic_blocks} {res.heuristic_s*1e6:9.1f}us"
             f" -> {res.best_blocks} {res.best_s*1e6:9.1f}us  x{res.speedup:.2f}"
-            f"  roofline {res.roofline_fraction:.2e}  ({res.evaluated} timed,"
+            f"  roofline {res.roofline_fraction}  ({res.evaluated} timed,"
             f" {res.rejected} lint-rejected)",
             file=sys.stderr, flush=True,
         )

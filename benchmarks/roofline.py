@@ -21,10 +21,11 @@ from dataclasses import dataclass
 
 from repro.configs import SHAPES, get_arch
 
-# TPU v5e hardware constants (per chip) — single source of truth in
-# repro.tune.roofline, shared with the kernel autotuner's per-winner
-# achieved-vs-roofline fractions
-from repro.tune.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+# the dry run targets a v5e pod: its published per-chip peaks, kept with
+# their source in repro.tune.roofline
+from repro.tune.roofline import V5E
+
+PEAK_FLOPS, HBM_BW, ICI_BW = V5E.flops, V5E.hbm_bw, V5E.ici_link_bw
 
 CHIPS = {"pod1": 256, "pod2": 512}
 

@@ -669,6 +669,9 @@ def main() -> int:
         "recompile census) of the report",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import numpy as np

@@ -1,7 +1,9 @@
 """Fleet capacity planning — size population lanes against device memory.
 
 A population chunk holds, per member, fp32 master params plus the two AdamW
-moments; with a 2-D ``("pop", "model")`` fleet mesh that state is sharded
+moments, and each vmapped update holds that member's gradients and its new
+params and moments besides; with a 2-D ``("pop", "model")`` fleet mesh that
+state is sharded
 ``model_extent``-ways within each pop slice (see ``fleet/sharding.py``), so
 the members a single device can hold grows linearly with the model axis.
 ``suggest_population_size`` turns (arch, mesh, per-device memory) into a
@@ -23,24 +25,34 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-__all__ = ["suggest_population_size", "kernel_vmem_reserve"]
+__all__ = ["BYTES_PER_PARAM", "suggest_population_size", "kernel_vmem_reserve"]
 
-# fp32 master params + fp32 AdamW m and v (repro.train.optimizer defaults;
-# 'bfloat16' moment_dtype would be 4 + 2 + 2)
-_DEFAULT_BYTES_PER_PARAM = 12
-# no backend-reported limit (host CPU backends): assume a v5e-class 16 GiB
-_FALLBACK_DEVICE_BYTES = 16 << 30
+# Per member and parameter: fp32 master params + fp32 AdamW m and v (12 B)
+# resident, plus what one vmapped update holds at once: fp32 gradients (4 B),
+# the new params, m and v the budget select picks from (12 B), and the bf16
+# weight casts and their masked copies (4 B). Compiled for a TPU v5e, the
+# one-member smollm-135m fit program needs 4.4 GiB, 35 B/param; resident
+# state alone (12 B) would size a 16 GiB chip at five members, whose
+# program cannot fit.
+BYTES_PER_PARAM = 32
+# the host CPU backend reports no limit: plan CPU runs as for a 16 GiB v5e
+_CPU_PLANNING_BYTES = 16 << 30
 
 
 def _device_memory_bytes(mesh: Optional[Mesh]) -> int:
+    """The device's reported ``bytes_limit``. Only the CPU backend, which
+    reports none, is planned at a fixed 16 GiB; an accelerator that reports
+    no limit is an error."""
     dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
-    try:
-        stats = dev.memory_stats()
-    except Exception:
-        stats = None
+    stats = dev.memory_stats() if dev.platform != "cpu" else None
     if stats and stats.get("bytes_limit"):
         return int(stats["bytes_limit"])
-    return _FALLBACK_DEVICE_BYTES
+    if dev.platform == "cpu":
+        return _CPU_PLANNING_BYTES
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no bytes_limit; "
+        "pass hbm_bytes= explicitly"
+    )
 
 
 def kernel_vmem_reserve(cache=None) -> int:
@@ -65,7 +77,7 @@ def suggest_population_size(
     *,
     hbm_bytes: Optional[int] = None,
     headroom: float = 0.6,
-    bytes_per_param: int = _DEFAULT_BYTES_PER_PARAM,
+    bytes_per_param: int = BYTES_PER_PARAM,
     max_members_per_lane: int = 64,
     reserve_kernel_vmem: bool = False,
     tuning_cache=None,
@@ -77,12 +89,14 @@ def suggest_population_size(
     cfg : ArchConfig — ``cfg.param_count()`` sets the per-member state size.
     mesh : fleet mesh (1-D pop or 2-D pop x model). None = a single lane on
         the default device (the vmap engine's situation).
-    hbm_bytes : per-device memory budget; default: the backend's reported
-        ``bytes_limit`` when available, else 16 GiB.
+    hbm_bytes : per-device memory budget; default: the device's reported
+        ``bytes_limit`` — 16 GiB on the CPU backend, which reports none; an
+        accelerator that reports none raises.
     headroom : fraction of ``hbm_bytes`` the member state may use — the rest
         is activations/gradients for the in-flight update and XLA scratch.
-    bytes_per_param : resident optimizer+param bytes per parameter per
-        member (default fp32 params + fp32 AdamW moments = 12).
+    bytes_per_param : bytes per parameter per member: resident params and
+        optimizer state plus the update's transient copies
+        (:data:`BYTES_PER_PARAM`).
     max_members_per_lane : cap on members per pop slice (compile-shape and
         latency guard, matching ``population_size`` chunking semantics).
     reserve_kernel_vmem : opt-in — subtract :func:`kernel_vmem_reserve` from

@@ -35,8 +35,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.masking import FaultContext, healthy, stack_contexts
 from repro.launch.mesh import make_pop_mesh
@@ -251,8 +250,19 @@ class ShardedFleetServeEngine:
         self._page_bytes = page_bytes(cfg, page_size)
         self.params_list = list(params_list)
         self.ctxs = [c or healthy() for c in ctxs]
-        self.params = _stack_trees(self.params_list)
+        # chip-stacked state lives split over the pop axis, each device
+        # holding its own chips' slice, so the fused step reads it in place
+        # instead of scattering it from one device on every dispatch
+        self._chip_sharding = NamedSharding(mesh, P(axis_name))
+        self.params = jax.device_put(
+            _stack_trees(self.params_list), self._chip_sharding
+        )
         self.ctx = stack_contexts(self.ctxs)
+        if self.ctx.ok is not None:
+            self.ctx = FaultContext(
+                ok=jax.device_put(self.ctx.ok, self._chip_sharding),
+                mode=self.ctx.mode,
+            )
 
         sample = make_sample_decode(cfg, pad_id=pad_id)
         mode = self.ctx.mode
@@ -286,12 +296,12 @@ class ShardedFleetServeEngine:
         # (repro.analysis DON001); params and the stacked ok masks are
         # reused across dispatches and stay undonated
         self._step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 vmapped,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=(pa,) * 7,
-                check_rep=False,
+                check_vma=False,
             ),
             donate_argnums=donate,
         )
@@ -507,6 +517,9 @@ class ShardedFleetServeEngine:
         remaining = jnp.zeros((N, S), jnp.int32)
         key = key if key is not None else jax.random.PRNGKey(0)
         keys = jax.random.split(key, N)  # one sample stream per chip
+        cache, cur, active, remaining, keys = jax.device_put(
+            (cache, cur, active, remaining, keys), self._chip_sharding
+        )
         temp = jnp.float32(temperature)
         eos = jnp.asarray(-1 if eos_id is None else eos_id, jnp.int32)
 

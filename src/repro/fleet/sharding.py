@@ -54,7 +54,6 @@ import math
 from typing import Any, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.fleet.scheduler import round_up_to_multiple
@@ -238,15 +237,16 @@ class ShardedPopulationEngine(PopulationFATEngine):
 
     def _shard(self, run, in_specs):
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 run,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=P(self.axis_name),
-                check_rep=False,  # per-shard loop trip counts legitimately diverge
-                # trailing mesh axes stay under compiler (GSPMD) control so the
-                # model rules can shard member state within each pop slice
-                auto=frozenset(self.model_axes),
+                check_vma=False,  # per-shard loop trip counts legitimately diverge
+                # only the pop axis is manual: trailing mesh axes stay under
+                # compiler (GSPMD) control so the model rules can shard member
+                # state within each pop slice
+                axis_names=frozenset({self.axis_name}),
             )
         )
 

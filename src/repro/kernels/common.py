@@ -4,11 +4,8 @@ Every kernel package (masked_matmul, flash_attention, decode_attention,
 mamba_scan) builds on this module instead of re-implementing the same
 plumbing four slightly-different ways:
 
-* **JAX-version compatibility** — the TPU compiler-params class has been
-  renamed across JAX releases (``pltpu.TPUCompilerParams`` in 0.4.x/0.5.x,
-  ``pltpu.CompilerParams`` in newer releases; very old versions take a raw
-  ``{"mosaic": {...}}`` dict).  :func:`tpu_compiler_params` is the single
-  place in the repo that touches either spelling.
+* **Compiler params** — :func:`tpu_compiler_params` builds the
+  ``pltpu.CompilerParams`` every ``pallas_call`` takes.
 * **Backend autodetection** — :func:`resolve_interpret` turns
   ``interpret=None`` into ``True`` off-TPU so every kernel entry point runs
   on CPU (Pallas interpret mode) without the caller knowing the backend.
@@ -52,7 +49,7 @@ __all__ = [
 
 
 # ---------------------------------------------------------------------------
-# JAX-version compatibility shim
+# Compiler params
 # ---------------------------------------------------------------------------
 
 
@@ -60,28 +57,11 @@ def tpu_compiler_params(
     *,
     dimension_semantics: Optional[Sequence[str]] = None,
     **kwargs: Any,
-):
-    """Build the ``compiler_params`` argument for ``pl.pallas_call``.
-
-    Resolves, at call time, whichever TPU compiler-params spelling the
-    installed JAX provides:
-
-    * ``pltpu.CompilerParams``    (newer JAX)
-    * ``pltpu.TPUCompilerParams`` (JAX 0.4.x / 0.5.x)
-    * a raw ``{"mosaic": {...}}`` dict (very old JAX)
-
-    This is the only place in the repository allowed to reference either
-    class name — kernels must call this instead.
-    """
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    params = dict(kwargs)
+) -> pltpu.CompilerParams:
+    """Build the ``compiler_params`` argument for ``pl.pallas_call``."""
     if dimension_semantics is not None:
-        params["dimension_semantics"] = tuple(dimension_semantics)
-    if cls is None:  # pre-dataclass JAX: pallas_call takes a nested dict
-        return {"mosaic": params}
-    return cls(**params)
+        kwargs["dimension_semantics"] = tuple(dimension_semantics)
+    return pltpu.CompilerParams(**kwargs)
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,11 @@ an XLA-level dequant materializes a bf16 copy and makes traffic WORSE
 (int8 read + bf16 write + bf16 read). This kernel fuses it:
 
 grid (B*Hq, S/bkv), kv innermost; each step DMAs an int8 (bkv, D) block +
-its (bkv,) scales, dequantizes in VMEM, and runs the online-softmax
-update. q (one token, padded to 8 sublanes) stays resident.
+its scales, dequantizes in VMEM, and runs the online-softmax update. q (one
+token, padded to 8 sublanes) stays resident. The scales travel as a trailing
+unit axis, (bkv, 1) blocks: Mosaic takes a block whose last two dims are
+multiples of (8, 128) or the array's own, and a (bkv,) row of a (rows, S)
+array is neither.
 """
 from __future__ import annotations
 
@@ -31,7 +34,7 @@ def _kernel(
     len_ref,  # scalar prefetch: (1,) int32 valid kv length
     q_ref,  # (1, bq, d)
     k_ref,  # (1, bkv, d) int8
-    ks_ref,  # (1, bkv) f32
+    ks_ref,  # (1, bkv, 1) f32
     v_ref,
     vs_ref,
     o_ref,  # (1, bq, d)
@@ -58,7 +61,7 @@ def _kernel(
     @pl.when(kv_start < valid)
     def _update():
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None]  # dequant in VMEM
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0]  # dequant in VMEM
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bkv)
@@ -72,7 +75,7 @@ def _kernel(
         l_ref[...] = jnp.broadcast_to(
             l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
         )
-        v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
+        v = v_ref[0].astype(jnp.float32) * vs_ref[0]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -89,7 +92,7 @@ def _paged_kernel(
     len_ref,  # scalar prefetch: (B,) int32 valid kv lengths
     q_ref,  # (1, gq, d) — one kv-head's query group, padded to >= 8 sublanes
     k_ref,  # (1, 1, page, d) int8 — the page picked by the block table
-    ks_ref,  # (1, 1, page) f32
+    ks_ref,  # (1, 1, page, 1) f32
     v_ref,
     vs_ref,
     o_ref,  # (1, gq, d)
@@ -118,7 +121,7 @@ def _paged_kernel(
     @pl.when(kv_start < valid)
     def _update():
         q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # dequant in VMEM
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # dequant in VMEM
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (gq, page)
@@ -132,7 +135,7 @@ def _paged_kernel(
         l_ref[...] = jnp.broadcast_to(
             l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
         )
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -177,7 +180,7 @@ def paged_decode_attention_pallas(
 
     q_map = lambda bh_, i, tbl, lens: (bh_, 0, 0)
     kv_map = lambda bh_, i, tbl, lens: (bh_ % hkv, tbl[bh_ // hkv, i], 0, 0)
-    s_map = lambda bh_, i, tbl, lens: (bh_ % hkv, tbl[bh_ // hkv, i], 0)
+    s_map = lambda bh_, i, tbl, lens: (bh_ % hkv, tbl[bh_ // hkv, i], 0, 0)
 
     kernel = functools.partial(
         _paged_kernel, maxp=maxp, gq=gq, page=page, hkv=hkv, scale=scale
@@ -190,9 +193,9 @@ def paged_decode_attention_pallas(
             in_specs=[
                 pl.BlockSpec((1, gq, d), q_map),
                 pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page), s_map),
+                pl.BlockSpec((1, 1, page, 1), s_map),
                 pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page), s_map),
+                pl.BlockSpec((1, 1, page, 1), s_map),
             ],
             out_specs=pl.BlockSpec((1, gq, d), q_map),
             scratch_shapes=[
@@ -206,7 +209,7 @@ def paged_decode_attention_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(block_tables, seq_lens, q, k_pages_i8, k_scale, v_pages_i8, v_scale)
+    )(block_tables, seq_lens, q, k_pages_i8, k_scale[..., None], v_pages_i8, v_scale[..., None])
 
 
 @functools.partial(
@@ -238,7 +241,7 @@ def decode_attention_pallas(
     # index maps receive the scalar-prefetch ref as a trailing argument
     q_map = lambda h, k_, len_ref: (h, 0, 0)
     kv_map = lambda h, k_, len_ref: (h // hq_per_kv, k_, 0)
-    s_map = lambda h, k_, len_ref: (h // hq_per_kv, k_)
+    s_map = lambda h, k_, len_ref: (h // hq_per_kv, k_, 0)
 
     kernel = functools.partial(_kernel, nk=nk, bq=bq, bkv=bkv, scale=scale)
     return pl.pallas_call(
@@ -249,9 +252,9 @@ def decode_attention_pallas(
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_map),
                 pl.BlockSpec((1, bkv, d), kv_map),
-                pl.BlockSpec((1, bkv), s_map),
+                pl.BlockSpec((1, bkv, 1), s_map),
                 pl.BlockSpec((1, bkv, d), kv_map),
-                pl.BlockSpec((1, bkv), s_map),
+                pl.BlockSpec((1, bkv, 1), s_map),
             ],
             out_specs=pl.BlockSpec((1, bq, d), q_map),
             scratch_shapes=[
@@ -265,4 +268,4 @@ def decode_attention_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(kv_valid_len, q, k_i8, k_scale, v_i8, v_scale)
+    )(kv_valid_len, q, k_i8, k_scale[..., None], v_i8, v_scale[..., None])

@@ -5,7 +5,8 @@ disaster) or runs an L-step scan with per-step HBM round-trips. The TPU
 rethink: grid (B, D/bd, L/bl) with L innermost; the running state h (bd, N)
 lives in VMEM scratch across the whole L sweep, each grid step streams one
 (bl, bd) chunk of u/dt and (bl, N) of B/C through VMEM, runs the recurrence
-sequentially in-register (VPU), and writes the (bl, bd) output chunk. HBM
+sequentially in-register (VPU) one sublane tile of timesteps at a time, and
+writes the (bl, bd) output chunk. HBM
 traffic is exactly one read of the inputs + one write of y — the roofline
 floor for this bandwidth-bound op.
 """
@@ -21,7 +22,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import grid_for, resolve_interpret, tpu_compiler_params
 
 
-def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hlast_ref, h_ref, *, bl: int, nl: int):
+def _kernel(
+    u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hlast_ref, h_ref,
+    *, bl: int, nl: int, rows: int,
+):
     il = pl.program_id(2)
 
     @pl.when(il == 0)
@@ -31,23 +35,43 @@ def _kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hlast_ref, h_ref, 
     a = a_ref[...].astype(jnp.float32)  # (bd, N)
     dskip = d_ref[...].astype(jnp.float32)  # (1, bd)
 
-    def step(t, h):
-        u_t = u_ref[0, t].astype(jnp.float32)  # (bd,)
-        dt_t = dt_ref[0, t].astype(jnp.float32)  # (bd,)
-        b_t = b_ref[0, t].astype(jnp.float32)  # (N,)
-        c_t = c_ref[0, t].astype(jnp.float32)  # (N,)
-        da = jnp.exp(dt_t[:, None] * a)  # (bd, N)
-        h = da * h + (dt_t * u_t)[:, None] * b_t[None, :]
-        y_t = jnp.sum(h * c_t[None, :], axis=1) + dskip[0] * u_t
-        y_ref[0, t] = y_t.astype(y_ref.dtype)
+    def tile(g, h):
+        # Mosaic reads and writes whole sublane tiles: load ``rows``
+        # timesteps at an aligned offset, step through them in registers,
+        # and store the outputs back as one tile
+        t0 = pl.multiple_of(g * rows, rows)
+        span = pl.ds(t0, rows)
+        u = u_ref[0, span].astype(jnp.float32)  # (rows, bd)
+        dt = dt_ref[0, span].astype(jnp.float32)  # (rows, bd)
+        bm = b_ref[0, span].astype(jnp.float32)  # (rows, N)
+        cm = c_ref[0, span].astype(jnp.float32)  # (rows, N)
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+
+        def step(j, carry):
+            h, y = carry
+            pick = lambda x: jnp.sum(jnp.where(row_ids == j, x, 0.0), axis=0)
+            u_t, dt_t, b_t, c_t = pick(u), pick(dt), pick(bm), pick(cm)
+            da = jnp.exp(dt_t[:, None] * a)  # (bd, N)
+            h = da * h + (dt_t * u_t)[:, None] * b_t[None, :]
+            y_t = jnp.sum(h * c_t[None, :], axis=1) + dskip[0] * u_t
+            return h, jnp.where(row_ids == j, y_t[None, :], y)
+
+        h, y = jax.lax.fori_loop(0, rows, step, (h, jnp.zeros(u.shape, jnp.float32)))
+        y_ref[0, span] = y.astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, bl, step, h_ref[...])
+    h = jax.lax.fori_loop(0, bl // rows, tile, h_ref[...])
     h_ref[...] = h
 
     @pl.when(il == nl - 1)
     def _store_final():
         hlast_ref[0] = h
+
+
+def sublane_rows(dtype) -> int:
+    """Rows in one native sublane tile of ``dtype`` (8 for 32-bit, 16 for
+    16-bit): the scan's time block must be a multiple of it."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "bl", "interpret"))
@@ -69,11 +93,14 @@ def selective_scan_pallas(
     n = a.shape[1]
     bd = min(bd, dim)
     bl = min(bl, length)
+    rows = sublane_rows(u.dtype)
+    if bl % rows:
+        raise ValueError(f"time block {bl} is not a multiple of {rows} rows")
     (nd, nl) = grid_for((dim, length), (bd, bl))
     grid = (bsz, nd, nl)
     d2 = d.reshape(1, dim)
 
-    kernel = functools.partial(_kernel, bl=bl, nl=nl)
+    kernel = functools.partial(_kernel, bl=bl, nl=nl, rows=rows)
     y, hlast = pl.pallas_call(
         kernel,
         grid=grid,

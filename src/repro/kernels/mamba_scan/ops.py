@@ -11,7 +11,7 @@ from __future__ import annotations
 
 
 from repro.kernels.common import is_tpu_backend, pad_axes_to, pad_to_multiple, tuned_block
-from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas
+from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas, sublane_rows
 from repro.kernels.mamba_scan.ref import selective_scan_ref, selective_step_ref
 
 
@@ -35,7 +35,7 @@ def selective_scan(
     )
     bd, bl = blocks["bd"], blocks["bl"]
     bd_ = min(bd, dim)
-    bl_ = min(bl, length)
+    bl_ = pad_to_multiple(min(bl, length), sublane_rows(u.dtype))
     dim_p = pad_to_multiple(dim, bd_)
     len_p = pad_to_multiple(length, bl_)
     up = pad_axes_to(u, {1: len_p, 2: dim_p})

@@ -52,6 +52,9 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--out", default=None, help="write the full JSON report here")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.analysis import analyze_stack, default_baseline_path, load_baseline
 

@@ -56,6 +56,9 @@ def main() -> None:
                     help="write the health + alert summary JSON "
                          "(needs --probe-every)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.health_out and not args.probe_every:
         ap.error("--health-out needs --probe-every")
 

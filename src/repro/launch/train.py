@@ -30,6 +30,9 @@ def main() -> None:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--moe-impl", default="einsum", choices=["einsum", "scatter"])
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -1,37 +1,66 @@
 """Analytic roofline terms for the four Pallas kernels.
 
-One home for the hardware constants (previously duplicated between
-``benchmarks/roofline.py`` and ``benchmarks/hillclimb.py`` — both now
-import from here) plus per-kernel FLOP/byte models so the autotuner can
-record *achieved-vs-roofline fraction* next to every winner it caches:
+One home for the hardware peaks (``benchmarks/roofline.py`` and
+``benchmarks/hillclimb.py`` import them from here) plus per-kernel
+FLOP/byte models so the autotuner can record the *achieved-vs-roofline
+fraction* next to every winner it caches:
 
-    bound_s  = max(flops / PEAK_FLOPS, bytes / HBM_BW)
+    bound_s  = max(flops / peak_flops, bytes / hbm_bw)
     fraction = bound_s / measured_s
 
-On a real TPU the fraction is the genuine roofline headroom; in CPU
-interpret mode (tests, CI) it is a tiny bookkeeping number — the *ordering*
-of candidates is the signal there, and the committed snapshots record the
-backend next to the fraction so the two regimes can't be confused.
+The peaks are those of the device the time was measured on, looked up by
+its ``device_kind``; a kind missing from :data:`PEAKS` is an error, not a
+default. Pallas interpret mode runs on the host, so its times have no
+roofline: they record ``None``.
 """
 from __future__ import annotations
 
-from typing import Mapping
+from dataclasses import dataclass
+from typing import Mapping, Optional
 
 import jax.numpy as jnp
 
 __all__ = [
-    "PEAK_FLOPS",
-    "HBM_BW",
-    "ICI_BW",
+    "Peaks",
+    "PEAKS",
+    "V5E",
+    "peaks_for",
     "kernel_flops_bytes",
     "roofline_fraction",
 ]
 
-# TPU v5e hardware constants (per chip) — the same numbers the dry-run
-# roofline (benchmarks/roofline.py) and the SPerf hillclimb driver use.
-PEAK_FLOPS = 197e12  # bf16 FLOP/s
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link (conservative single-link budget)
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator generation."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    ici_link_bw: float  # chip-to-chip bytes/s over one link
+    source: str
+
+
+# keyed by ``jax.Device.device_kind``
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12,
+        hbm_bw=819e9,
+        ici_link_bw=50e9,  # 1,600 Gbit/s per chip over 4 links
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+V5E = PEAKS["TPU v5 lite"]
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; raises on a kind with no published row."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 def kernel_flops_bytes(kernel: str, shape: Mapping[str, int], dtype) -> tuple[float, float]:
@@ -73,10 +102,17 @@ def kernel_flops_bytes(kernel: str, shape: Mapping[str, int], dtype) -> tuple[fl
     raise ValueError(f"unknown kernel {kernel!r}")
 
 
-def roofline_fraction(flops: float, hbm_bytes: float, measured_s: float) -> float:
-    """Fraction of the compute/memory roofline the measured time achieves
-    (1.0 = running exactly at the analytic bound; small = headroom)."""
+def roofline_fraction(
+    flops: float, hbm_bytes: float, measured_s: float, device_kind: Optional[str]
+) -> Optional[float]:
+    """Fraction of the compute/memory roofline of ``device_kind`` that the
+    measured time achieves (1.0 = running exactly at the analytic bound;
+    small = headroom). ``device_kind=None`` marks a time taken in Pallas
+    interpret mode, which has no roofline: the result is None."""
+    if device_kind is None:
+        return None
+    peaks = peaks_for(device_kind)
     if measured_s <= 0:
         return 0.0
-    bound_s = max(flops / PEAK_FLOPS, hbm_bytes / HBM_BW)
+    bound_s = max(flops / peaks.flops, hbm_bytes / peaks.hbm_bw)
     return bound_s / measured_s

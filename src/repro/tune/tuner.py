@@ -239,7 +239,7 @@ class TuneResult:
     best_blocks: dict
     best_s: float
     speedup: float
-    roofline_fraction: float
+    roofline_fraction: Optional[float]  # None for interpret-mode times
     vmem_bytes: int
     evaluated: int
     rejected: int
@@ -396,7 +396,10 @@ def tune_kernel(
         best_blocks=best,
         best_s=best_s,
         speedup=heuristic_s / best_s if best_s > 0 else float("inf"),
-        roofline_fraction=roofline_fraction(flops, byts, best_s),
+        roofline_fraction=roofline_fraction(
+            flops, byts, best_s,
+            None if backend == "interpret" else jax.devices()[0].device_kind,
+        ),
         vmem_bytes=best_vmem,
         evaluated=len(timed),
         rejected=len(rejected),

@@ -25,6 +25,7 @@ from repro.analysis import (
 from repro.analysis.kernelgeom import (
     decode_attention_launch,
     flash_attention_launch,
+    mamba_scan_launch,
     masked_matmul_launch,
 )
 from repro.analysis.recompile import census
@@ -258,6 +259,34 @@ def test_kernel_flags_mask_period_incompatibility():
     assert _codes(check_launch(bad)) == ["KRN001"]
 
 
+@pytest.mark.parametrize("paged", [False, True])
+def test_kernel_flags_old_decode_scale_blocks(paged):
+    """The int8 decode kernels once carried their scales as (1, bkv) rows of
+    a (B*Hkv, S) array (dense) and (1, 1, page) rows of an (Hkv, P, page)
+    pool (paged). Mosaic refused both: a second-last block dim of 1 is
+    neither a multiple of 8 nor the array's own."""
+    if paged:
+        scale_block, scale_arr = (1, 1, 16), (3, 512, 16)
+        kv_block, kv_arr = (1, 1, 16, 64), (3, 512, 16, 64)
+    else:
+        scale_block, scale_arr = (1, 128), (12, 2048)
+        kv_block, kv_arr = (1, 128, 64), (12, 2048, 64)
+    bad = KernelLaunch(
+        kernel="golden.decode_scales",
+        dims=(36, 8, 2048),
+        blocks=(1, 8, 128),
+        vmem_blocks=(
+            ((1, 8, 64), jnp.float32, True, (36, 8, 64)),  # q block
+            (kv_block, jnp.int8, True, kv_arr),  # k block
+            (scale_block, jnp.float32, True, scale_arr),  # k scales
+        ),
+    )
+    findings = check_launch(bad)
+    assert _codes(findings) == ["KRN001"]
+    assert findings[0].subject == "block2"
+    assert "(8, 128)" in findings[0].message
+
+
 def test_kernel_flags_vmem_overflow():
     bad = KernelLaunch(
         kernel="golden.fat",
@@ -306,6 +335,9 @@ def test_kernel_builders_clean_at_stack_shapes():
                                 cfg.resolved_head_dim),
         decode_attention_launch(4, cfg.num_heads, cfg.num_kv_heads, 4096,
                                 cfg.resolved_head_dim, paged=True, page_size=8),
+        decode_attention_launch(4, cfg.num_heads, cfg.num_kv_heads, 2048,
+                                cfg.resolved_head_dim, paged=True, page_size=16),
+        mamba_scan_launch(1, 512, 8192, 16, dtype=jnp.bfloat16),
     ]
     for launch in launches:
         assert check_launch(launch) == [], launch.kernel

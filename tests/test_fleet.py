@@ -28,6 +28,7 @@ from repro.fleet import (
     ShardedPopulationEngine,
     suggest_population_size,
 )
+from repro.fleet.capacity import BYTES_PER_PARAM
 from repro.launch.mesh import make_fleet_mesh, make_pop_mesh
 from repro.models import model as M
 from repro.serve.engine import ServeEngine
@@ -229,11 +230,30 @@ def test_sharded_engine_2d_mesh_requires_layout(trainers):
         ShardedPopulationEngine(cfg=CFG, compute="bogus", **kw)
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_device_memory_fallback_only_on_cpu(platform):
+    from types import SimpleNamespace
+
+    from repro.fleet.capacity import _device_memory_bytes
+
+    dev = SimpleNamespace(
+        platform=platform, device_kind="fake", memory_stats=lambda: {}
+    )
+    mesh = SimpleNamespace(devices=np.array([dev], dtype=object))
+    if platform == "cpu":
+        assert _device_memory_bytes(mesh) == 16 << 30
+    else:
+        with pytest.raises(RuntimeError, match="reports no bytes_limit"):
+            _device_memory_bytes(mesh)
+        dev.memory_stats = lambda: {"bytes_limit": 123}
+        assert _device_memory_bytes(mesh) == 123
+
+
 def test_suggest_population_size_scales_with_model_axis():
     dev = jax.devices()[0]
     mesh_1d = jax.sharding.Mesh(np.array([dev] * 4), ("pop",))
     mesh_2d = jax.sharding.Mesh(np.array([dev] * 8).reshape(4, 2), ("pop", "model"))
-    budget = CFG.param_count() * 12 * 3  # three members' state per device
+    budget = CFG.param_count() * BYTES_PER_PARAM * 3  # three members per device
     flat = suggest_population_size(CFG, mesh_1d, hbm_bytes=budget, headroom=1.0)
     tp = suggest_population_size(CFG, mesh_2d, hbm_bytes=budget, headroom=1.0)
     assert flat == 3 * 4  # 3 members per lane x 4 lanes

@@ -1,11 +1,9 @@
 """Unit tests for the shared kernel-runtime layer (repro.kernels.common):
-the JAX-version compiler-params shim, pad/unpad geometry, backend
+the compiler-params helper, pad/unpad geometry, backend
 autodetection, and the per-dtype tolerance table.
 
 ``hypothesis`` is optional (same contract as tests/test_core.py): without
 it only the ``choose_block`` property tests skip."""
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,59 +32,37 @@ from repro.kernels import common
 
 
 # ---------------------------------------------------------------------------
-# compiler-params shim (both JAX API spellings + dict fallback)
+# compiler params (the one pltpu.CompilerParams spelling)
 # ---------------------------------------------------------------------------
 
 
-class _NewStyleParams:
-    """Stands in for pltpu.CompilerParams (newer JAX)."""
+@pytest.mark.parametrize(
+    "semantics,extra",
+    [
+        (("parallel", "arbitrary"), {}),
+        (["parallel"], {"vmem_limit_bytes": 1 << 20}),
+        (None, {}),
+    ],
+)
+def test_compiler_params_fields(semantics, extra):
+    from jax.experimental.pallas import tpu as pltpu
 
-    def __init__(self, dimension_semantics=None, **kw):
-        self.dimension_semantics = dimension_semantics
-        self.extra = kw
-
-
-class _OldStyleParams:
-    """Stands in for pltpu.TPUCompilerParams (JAX 0.4.x/0.5.x)."""
-
-    def __init__(self, dimension_semantics=None, **kw):
-        self.dimension_semantics = dimension_semantics
-        self.extra = kw
-
-
-def test_shim_prefers_new_spelling(monkeypatch):
-    fake = types.SimpleNamespace(
-        CompilerParams=_NewStyleParams, TPUCompilerParams=_OldStyleParams
-    )
-    monkeypatch.setattr(common, "pltpu", fake)
-    out = common.tpu_compiler_params(dimension_semantics=("parallel", "arbitrary"))
-    assert isinstance(out, _NewStyleParams)
-    assert out.dimension_semantics == ("parallel", "arbitrary")
-
-
-def test_shim_falls_back_to_old_spelling(monkeypatch):
-    fake = types.SimpleNamespace(TPUCompilerParams=_OldStyleParams)
-    monkeypatch.setattr(common, "pltpu", fake)
-    out = common.tpu_compiler_params(
-        dimension_semantics=("parallel",), vmem_limit_bytes=1 << 20
-    )
-    assert isinstance(out, _OldStyleParams)
-    assert out.dimension_semantics == ("parallel",)
-    assert out.extra == {"vmem_limit_bytes": 1 << 20}
-
-
-def test_shim_dict_fallback_when_neither_exists(monkeypatch):
-    monkeypatch.setattr(common, "pltpu", types.SimpleNamespace())
-    out = common.tpu_compiler_params(dimension_semantics=("arbitrary",))
-    assert out == {"mosaic": {"dimension_semantics": ("arbitrary",)}}
+    out = common.tpu_compiler_params(dimension_semantics=semantics, **extra)
+    assert isinstance(out, pltpu.CompilerParams)
+    if semantics is not None:
+        assert out.dimension_semantics == tuple(semantics)
+    for k, v in extra.items():
+        assert getattr(out, k) == v
 
 
 def test_shim_works_against_installed_jax():
-    # whatever the installed JAX calls it, the shim must build something
+    from jax.experimental.pallas import tpu as pltpu
+
     out = common.tpu_compiler_params(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
-    assert out is not None
+    assert isinstance(out, pltpu.CompilerParams)
+    assert out.dimension_semantics == ("parallel", "parallel", "arbitrary")
 
 
 # ---------------------------------------------------------------------------

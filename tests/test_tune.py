@@ -378,7 +378,7 @@ def test_tune_masked_matmul_beats_or_ties_heuristic(isolated_cache):
     assert res.backend == "interpret"
     assert res.evaluated >= 1
     assert res.vmem_bytes > 0
-    assert 0.0 <= res.roofline_fraction <= 1.0
+    assert res.roofline_fraction is None  # interpret mode has no roofline
     # the cache entry round-trips through the seam
     kernel, pshape, dtype, backend = parse_key(res.key)
     assert (kernel, pshape, dtype, backend) == (
@@ -418,10 +418,10 @@ def test_kernel_vmem_reserve_sums_per_kernel_maxima():
 
 def test_suggest_population_size_reserve_is_opt_in_and_shrinks():
     from repro.configs import get_arch
-    from repro.fleet.capacity import suggest_population_size
+    from repro.fleet.capacity import BYTES_PER_PARAM, suggest_population_size
 
     cfg = get_arch("paper-mlp")
-    member = int(cfg.param_count()) * 12
+    member = int(cfg.param_count()) * BYTES_PER_PARAM
     cache = TuningCache()
     cache.put(cache_key("masked_matmul", dict(m=8), "float32", "cpu"),
               dict(blocks=dict(bm=8), vmem_bytes=4 * member))
@@ -443,3 +443,19 @@ def test_suggest_population_size_reserve_is_opt_in_and_shrinks():
                     dict(blocks=dict(bm=8), vmem_bytes=4 * member),
             }),
         )
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.tune.roofline import PEAKS, peaks_for, roofline_fraction
+
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    assert set(PEAKS) == {"TPU v5 lite"}
+    # 819 MB in 1 ms is exactly the HBM bound: share 1.0
+    assert roofline_fraction(0.0, 819e6, 1e-3, "TPU v5 lite") == pytest.approx(1.0)
+    assert roofline_fraction(0.0, 819e6, 1e-3, None) is None
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")
+    with pytest.raises(KeyError):
+        roofline_fraction(1.0, 1.0, 1.0, "TPU v9 imaginary")
