@@ -26,7 +26,13 @@ import jax.numpy as jnp
 from repro.core.faults import FaultMap
 from repro.core.mapping import masked_weight
 
+# the name scope around each use-site mask (its construction and the
+# multiply, not the GEMM): the compiled program's ops under it are what the
+# serving engine publishes as its fault-mask ops (``mask_ops``)
+MASK_SCOPE = "fault_mask"
+
 __all__ = [
+    "MASK_SCOPE",
     "FaultContext",
     "fault_linear",
     "fault_einsum",
@@ -166,7 +172,9 @@ def fault_linear(
         from repro.kernels.masked_matmul import ops as mm_ops
 
         return mm_ops.masked_matmul(x, w, ctx.ok)
-    return jnp.matmul(x, masked_weight(w, ctx.ok), precision=precision)
+    with jax.named_scope(MASK_SCOPE):
+        w = masked_weight(w, ctx.ok)
+    return jnp.matmul(x, w, precision=precision)
 
 
 def fault_einsum(
@@ -184,7 +192,9 @@ def fault_einsum(
     if ctx is None or not ctx.active:
         return jnp.einsum(spec, x, w, precision=precision)
     _require_per_chip(ctx)
-    return jnp.einsum(spec, x, masked_weight(w, ctx.ok), precision=precision)
+    with jax.named_scope(MASK_SCOPE):
+        w = masked_weight(w, ctx.ok)
+    return jnp.einsum(spec, x, w, precision=precision)
 
 
 # ---------------------------------------------------------------------------

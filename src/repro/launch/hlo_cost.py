@@ -33,7 +33,9 @@ _DTYPE_BYTES = {
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _ASSIGN_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
-_OPCODE_RE = re.compile(r"([\w\-]+)\(")
+# opcodes are lower case; a TPU layout's tiling and memory space in the
+# result type (``{1,0:T(8,128)S(1)}``) are upper case and must not match
+_OPCODE_RE = re.compile(r"([a-z][\w\-]*)\(")
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*?\)\s*->.*\{\s*$")
 _TRIP_RE = re.compile(r'known_trip_count[^}]*?"n":"(\d+)"')
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
@@ -285,6 +287,27 @@ def input_output_aliases(hlo: str) -> list[IOAlias]:
             )
         break
     return out
+
+
+def module_name(hlo: str) -> str:
+    """The module's name from its ``HloModule <name>, ...`` header, e.g.
+    ``jit_sample_decode`` (a profiler trace names the program's runs so)."""
+    head = hlo.lstrip().split(None, 2)
+    if len(head) < 2 or head[0] != "HloModule":
+        raise ValueError("not HLO module text: no 'HloModule <name>' header")
+    return head[1].rstrip(",")
+
+
+def scoped_instructions(hlo: str, scope: str) -> list[str]:
+    """Names of the instructions whose ``op_name`` metadata lies under the
+    name scope ``scope`` (``jax.named_scope``), leaving out those inside
+    fused computations: what remains is what runs as an op of its own, and
+    so what a device trace names. A fusion carries its root's metadata."""
+    instrs = list(iter_instructions(hlo))
+    fused = {m for i in instrs if i.opcode == "fusion"
+             for m in re.findall(r"calls=%?([\w.\-]+)", i.line)}
+    under = re.compile(r'op_name="(?:[^"]*/)?' + re.escape(scope) + r'(?:/[^"]*)?"')
+    return sorted(i.name for i in instrs if i.computation not in fused and under.search(i.line))
 
 
 def entry_parameters(hlo: str) -> dict[int, Instruction]:

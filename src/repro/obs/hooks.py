@@ -11,7 +11,9 @@ Track layout (what Perfetto draws):
 
 * one track per decode slot (``slot3``, or ``chip1/slot3`` for the fleet)
   carrying that slot's ``admit``/``chunk`` spans, the per-request
-  ``decode`` span (admission → retirement) and the ``retire`` instant;
+  ``queue`` span (continuous engine: enqueue → first prefill dispatch; it
+  may overlap the slot's previous occupant), the per-request ``decode`` span (admission →
+  retirement) and the ``retire`` instant;
 * one ``engine`` track per process carrying the fused ``decode_step``
   dispatch spans;
 * one ``pages`` counter track per allocator (:class:`PoolMonitor`)
@@ -23,7 +25,6 @@ from typing import Optional
 
 from repro.obs.metrics import (
     QUEUE_WAIT_STEP_BUCKETS,
-    STEP_LATENCY_BUCKETS_S,
     TPOT_BUCKETS_S,
     TTFT_BUCKETS_S,
 )
@@ -34,8 +35,8 @@ __all__ = ["RequestTracer", "PoolMonitor"]
 
 class RequestTracer:
     """Per-request lifecycle spans on per-slot tracks, plus the request
-    latency histograms (TTFT, time-per-output-token, queue wait, prefill
-    latency) every serving tier records the same way."""
+    latency histograms (TTFT, time-per-output-token, queue wait) every
+    serving tier records the same way."""
 
     def __init__(self, recorder: Optional[Recorder], *, proc: str = "serve",
                  track_prefix: str = ""):
@@ -52,6 +53,14 @@ class RequestTracer:
 
     # -- admission ---------------------------------------------------------
 
+    def queued(self, rid: int, slot: int, t0: float, t1: float) -> None:
+        """Request ``rid`` waited from its ``enqueue`` instant ``t0`` to the
+        start ``t1`` of its first prefill dispatch (packed or chunked)."""
+        if not self.rec:
+            return
+        self.rec.span("queue", proc=self.proc, track=self._slot_track(slot),
+                      t0=t0, t1=t1, args=dict(rid=rid))
+
     def admitted(self, rid: int, slot: int, t0: float, t1: float, *,
                  args: Optional[dict] = None) -> None:
         """One request admitted by a prefill dispatch spanning [t0, t1]
@@ -61,7 +70,6 @@ class RequestTracer:
             return
         self.rec.span("admit", proc=self.proc, track=self._slot_track(slot),
                       t0=t0, t1=t1, args=dict(rid=rid, **(args or {})))
-        self.rec.observe("serve.prefill_admit_s", t1 - t0, STEP_LATENCY_BUCKETS_S)
         self._decode_t0[rid] = t1
 
     def chunk(self, rid: int, slot: int, t0: float, t1: float, *,
@@ -72,7 +80,6 @@ class RequestTracer:
             return
         self.rec.span("chunk", proc=self.proc, track=self._slot_track(slot),
                       t0=t0, t1=t1, args=dict(rid=rid, final=final, **(args or {})))
-        self.rec.observe("serve.prefill_chunk_s", t1 - t0, STEP_LATENCY_BUCKETS_S)
         if final:
             self._decode_t0[rid] = t1
 
@@ -85,7 +92,6 @@ class RequestTracer:
             return
         self.rec.span("decode_step", proc=self.proc, track=f"{self.prefix}engine",
                       t0=t0, t1=t1, args=dict(n_active=n_active, clock=clock))
-        self.rec.observe("serve.decode_step_s", t1 - t0, STEP_LATENCY_BUCKETS_S)
 
     # -- retirement --------------------------------------------------------
 

@@ -41,7 +41,7 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Iterator, Optional
+from typing import Any, Callable, Iterator, Optional
 
 from repro.obs.metrics import MetricsRegistry
 
@@ -151,24 +151,37 @@ class Recorder:
 
     @contextmanager
     def timed(self, name: str, *, proc: str = "serve", track: str = "engine",
-              args: Optional[dict] = None):
-        """Context manager emitting one span over the enclosed block."""
+              args: Optional[dict] = None,
+              annotate: Optional[Callable[[str], Any]] = None):
+        """Context manager emitting one span over the enclosed block.
+
+        ``annotate`` is a factory of a context manager taking the span's
+        name, e.g. ``jax.profiler.TraceAnnotation``: the block also runs
+        inside it, so the span lands on the profiler's host timeline, on the
+        device trace's clock, as well. Only when enabled, like the span."""
         if not self.enabled:
             yield
             return
         t0 = self.now()
         try:
-            yield
+            if annotate is None:
+                yield
+            else:
+                with annotate(name):
+                    yield
         finally:
             self.span(name, proc=proc, track=track, t0=t0, args=args)
 
     def instant(self, name: str, *, proc: str = "serve", track: str = "engine",
-                args: Optional[dict] = None) -> None:
+                args: Optional[dict] = None) -> Optional[float]:
+        """Record a point event; returns its timestamp (None when disabled)."""
         if not self.enabled:
-            return
+            return None
         s = time.perf_counter()
-        self._emit(Event("instant", name, proc, track, s - self.t0, args=args))
+        ts = s - self.t0
+        self._emit(Event("instant", name, proc, track, ts, args=args))
         self.self_time_s += time.perf_counter() - s
+        return ts
 
     def sample(self, name: str, value: float, *, proc: str = "serve",
                track: str = "engine") -> None:

@@ -42,13 +42,15 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.masking import FaultContext, healthy
+from repro.core.masking import MASK_SCOPE, FaultContext, healthy
+from repro.launch.hlo_cost import module_name, scoped_instructions
 from repro.models import model as M
 from repro.obs.alerts import AlertEngine, AlertRule
 from repro.obs.health import HealthConfig, HealthTracker
@@ -380,6 +382,9 @@ class ContinuousBatchingEngine:
         # _cache_size() then counts traffic-time compiles)
         self._aot: dict = {}
         self.used_programs: set = set()
+        # HLO module name -> names of its ops under the fault-mask scope
+        # (core/masking.py), from the AOT programs; see warmup()
+        self.mask_ops: dict[str, list[str]] = {}
         # fault detection (ROADMAP item 2): an ABFT prober dispatched every
         # probe_every decode dispatches, feeding the health state machine
         # and the alert engine. Probes are SEPARATE dispatches through a
@@ -535,9 +540,33 @@ class ContinuousBatchingEngine:
         fused decode step — ``jit(...).lower().compile()`` each, stored as
         executables the serve loop dispatches through directly. After
         warmup, traffic-time jit compiles (``compile_counts()``'s
-        ``jit_fallback``) stay at zero. Returns the AOT program count."""
+        ``jit_fallback``) stay at zero. Also maps each program's HLO module
+        name to its fault-mask ops (``mask_ops``), which ``serve()``
+        publishes so that a device trace's mask time can be told apart
+        (programs that share a module name, the bucket ladder's, share one
+        list). Returns the AOT program count."""
         if self.prefill_buckets is None:
             raise ValueError("warmup() needs bucketed prefill; prefill_buckets is None")
+        # mask_ops reads the programs' op metadata back, which JAX leaves out
+        # of its persistent compilation cache's key: a build that differs
+        # from a cached one only in its name scopes would load that one's
+        # executable. Under a fault mask, key on the metadata too.
+        keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed or self.ctx.active)
+        try:
+            self._compile_programs()
+        finally:
+            jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
+        mask_ops: dict = {}
+        for exe in self._aot.values():
+            hlo = exe.as_text()
+            mask_ops.setdefault(module_name(hlo), set()).update(
+                scoped_instructions(hlo, MASK_SCOPE))
+        self.mask_ops = {m: sorted(ops) for m, ops in mask_ops.items()}
+        return len(self._aot)
+
+    def _compile_programs(self) -> None:
         params_s = shape_structs(self.params)
         ctx_s = shape_structs(self.ctx)
         cache, cur, active, remaining = self._state_structs()
@@ -565,7 +594,6 @@ class ContinuousBatchingEngine:
                 params_s, cur, cache, shape_structs(jax.random.PRNGKey(0)), ctx_s,
                 jax.ShapeDtypeStruct((), jnp.float32), active, i32(), remaining,
             ).compile()
-        return len(self._aot)
 
     def compile_counts(self) -> dict:
         """Compile accounting: AOT executables (warmup), traffic-time jit
@@ -597,7 +625,10 @@ class ContinuousBatchingEngine:
         stats). Outputs include per-request TTFT, queue wait and finish
         reason. ``on_step(clock)`` runs at the top of every scheduler
         round — the injection hook benchmarks use to flip silicon
-        mid-serve (``set_silicon``)."""
+        mid-serve (``set_silicon``), called before the round's span opens.
+        With a recorder attached, each round and each of its stages is a
+        span on the ``host`` track and a profiler annotation of the same
+        name (``src/repro/obs/README.md``, "Round stages")."""
         if not requests:
             return {}, ServeStats(num_slots=self.num_slots, page_size=self.page_size)
         alloc = PageAllocator(self.num_pages, self.page_size)
@@ -606,7 +637,14 @@ class ContinuousBatchingEngine:
         rec = self.obs
         tracer = RequestTracer(rec, proc="serve")
         pool = PoolMonitor(rec, alloc, proc="serve")
-        enqueued: set = set()
+        enqueued: dict = {}  # rid -> its enqueue time, until its first prefill dispatch
+        # each stage of a round is a span on the `host` track and, under
+        # the same name, a profiler annotation on the device trace's clock
+        stage = partial(rec.timed, proc="serve", track="host",
+                        annotate=jax.profiler.TraceAnnotation)
+        if rec:
+            rec.instant("serve.programs", proc="serve", track="engine",
+                        args=dict(fault_mask=self.mask_ops))
 
         V = self.cfg.vocab_size
         dtype = jnp.dtype(self.cfg.dtype)
@@ -624,6 +662,21 @@ class ContinuousBatchingEngine:
         top = buckets[-1] if buckets else None
         pack: list[PackItem] = []
 
+        def dispatch(pkey, jitted, *args):
+            """Run program ``pkey``: its AOT executable, else the jit
+            wrapper, recording a ``compile.fallback`` when that compiled."""
+            fn = self._aot.get(pkey)
+            if fn is not None:
+                out = fn(*args)
+            else:
+                n = jitted._cache_size()
+                out = jitted(*args)
+                if rec and jitted._cache_size() > n:
+                    rec.instant("compile.fallback", proc="serve", track="engine",
+                                args=dict(program=str(pkey), clock=clock))
+            self.used_programs.add(pkey)
+            return out
+
         def flush_pack():
             nonlocal cache, cur, active, remaining
             if not pack:
@@ -635,22 +688,22 @@ class ContinuousBatchingEngine:
                 page_size=self.page_size, max_pages_per_seq=self.max_pages_per_seq,
                 num_slots=self.num_slots, pad_id=self.pad_id,
             )
-            pkey = ("prefill_admit", width)
-            fn = self._aot.get(pkey, self._packed_admit)
             t0 = rec.now() if rec else 0.0
-            cache, cur, active, remaining = fn(
+            cache, cur, active, remaining = dispatch(
+                ("prefill_admit", width), self._packed_admit,
                 self.params, arrays["tokens"], arrays["positions"],
                 arrays["segments"], self.ctx, cache, cur, active, remaining,
                 arrays["page_ix"], arrays["page_off"], arrays["gather_pos"],
                 arrays["slots"], arrays["rows"], arrays["seq_lens"],
                 arrays["budgets"],
             )
-            self.used_programs.add(pkey)
             stats.prefill_dispatches += 1
             if rec:
-                jax.block_until_ready(cur)
+                with stage("prefill.wait"):
+                    jax.block_until_ready(cur)
                 t1 = rec.now()
                 for it in pack:
+                    tracer.queued(it.rid, it.slot, enqueued.pop(it.rid), t0)
                     tracer.admitted(
                         it.rid, it.slot, t0, t1,
                         args=dict(bucket=width, packed=len(pack),
@@ -670,20 +723,21 @@ class ContinuousBatchingEngine:
                 maps = chunk_step_maps(st, pages, page_size=self.page_size)
                 ct = np.full((st.size,), self.pad_id, np.int32)
                 ct[: st.valid] = toks[st.start : st.start + st.valid]
-                ckey = ("prefill_chunk", st.size)
-                fn = self._aot.get(ckey, self._prefill_chunk)
                 t0 = rec.now() if rec else 0.0
-                cache, cur, active, remaining = fn(
+                cache, cur, active, remaining = dispatch(
+                    ("prefill_chunk", st.size), self._prefill_chunk,
                     self.params, ct[None], self.ctx, cache, cur, active,
                     remaining, np.int32(slot), row, maps["page_ix"],
                     maps["page_off"], np.int32(st.start), np.int32(st.valid),
                     np.int32(r.max_new_tokens), np.bool_(st.final),
                 )
-                self.used_programs.add(ckey)
                 stats.prefill_dispatches += 1
                 stats.chunk_dispatches += 1
                 if rec:
-                    jax.block_until_ready(cur)
+                    with stage("prefill.wait"):
+                        jax.block_until_ready(cur)
+                    if r.rid in enqueued:  # its first chunk
+                        tracer.queued(r.rid, slot, enqueued.pop(r.rid), t0)
                     tracer.chunk(
                         r.rid, slot, t0, rec.now(), final=st.final,
                         args=dict(size=st.size, start=st.start, valid=st.valid),
@@ -693,95 +747,104 @@ class ContinuousBatchingEngine:
         while not table.done:
             if on_step is not None:
                 on_step(clock)
-            table.stamp_arrivals(clock)
-            if rec:
-                for r in table.pending:
-                    if r.arrival > clock:
-                        break  # pending is arrival-sorted
-                    if r.rid not in enqueued:
-                        enqueued.add(r.rid)
-                        rec.instant("enqueue", proc="serve", track="engine",
-                                    args=dict(rid=r.rid, arrival=r.arrival,
-                                              clock=clock))
-            # admissions: fill free slots with every arrived request we can,
-            # packing short prompts into shared bucket dispatches
-            while True:
-                adm = table.pop_admission(clock)
-                if adm is None:
-                    break
-                slot, r, pages = adm
-                table.outputs_admitted[r.rid] = clock
-                stats.admitted += 1
-                plen = len(r.tokens)
-                if top is not None and plen > top:
+            round_annotation = partial(jax.profiler.StepTraceAnnotation, step_num=clock)
+            with rec.timed("serve_round", proc="serve", track="host",
+                           args=dict(clock=clock), annotate=round_annotation):
+                with stage("schedule"):
+                    table.stamp_arrivals(clock)
+                    if rec:
+                        for r in table.pending:
+                            if r.arrival > clock:
+                                break  # pending is arrival-sorted
+                            if r.rid not in enqueued:
+                                enqueued[r.rid] = rec.instant(
+                                    "enqueue", proc="serve", track="engine",
+                                    args=dict(rid=r.rid, arrival=r.arrival, clock=clock))
+                    # admissions: fill free slots with every arrived request we
+                    # can, packing short prompts into shared bucket dispatches
+                    while True:
+                        adm = table.pop_admission(clock)
+                        if adm is None:
+                            break
+                        slot, r, pages = adm
+                        table.outputs_admitted[r.rid] = clock
+                        stats.admitted += 1
+                        plen = len(r.tokens)
+                        if top is not None and plen > top:
+                            flush_pack()
+                            run_chunks(slot, r, pages)
+                            continue
+                        if pack and (
+                            len(pack) >= self.max_pack
+                            or (top is not None and sum(len(i.tokens) for i in pack) + plen > top)
+                        ):
+                            flush_pack()
+                        pack.append(
+                            PackItem(np.asarray(r.tokens, np.int32), slot, tuple(pages),
+                                     r.max_new_tokens, rid=r.rid)
+                        )
                     flush_pack()
-                    run_chunks(slot, r, pages)
+                    stats.peak_resident_kv_bytes = max(
+                        stats.peak_resident_kv_bytes, alloc.pages_in_use * self._page_bytes
+                    )
+                    pool.sample()
+                if not table.active.any():
+                    # idle: jump the clock to the next arrival (no dispatches)
+                    nxt = table.next_arrival()
+                    assert nxt is not None and nxt > clock
+                    clock = nxt
                     continue
-                if pack and (
-                    len(pack) >= self.max_pack
-                    or (top is not None and sum(len(i.tokens) for i in pack) + plen > top)
-                ):
-                    flush_pack()
-                pack.append(
-                    PackItem(np.asarray(r.tokens, np.int32), slot, tuple(pages),
-                             r.max_new_tokens, rid=r.rid)
-                )
-            flush_pack()
-            stats.peak_resident_kv_bytes = max(
-                stats.peak_resident_kv_bytes, alloc.pages_in_use * self._page_bytes
-            )
-            pool.sample()
-            if not table.active.any():
-                # idle: jump the clock to the next arrival (no dispatches)
-                nxt = table.next_arrival()
-                assert nxt is not None and nxt > clock
-                clock = nxt
-                continue
 
-            n_active = int(table.active.sum())
-            dfn = self._aot.get(("decode",), self._sample_decode)
-            t0 = rec.now() if rec else 0.0
-            emitted, tok_lp, cur, cache, key, active, remaining = dfn(
-                self.params, cur, cache, key, self.ctx, temp, active, eos, remaining
-            )
-            self.used_programs.add(("decode",))
-            clock += 1
-            stats.decode_dispatches += 1
-            stats.emitted_tokens += n_active
-            stats.active_slot_steps += n_active
-            stats.kv_byte_steps += alloc.pages_in_use * self._page_bytes
-            em = np.asarray(emitted)  # forces the dispatch to completion
-            lp = np.asarray(tok_lp)
-            ac = np.asarray(active)
-            if rec:
-                t1 = rec.now()
-                tracer.decode_dispatch(t0, t1, n_active=n_active, clock=clock)
-                slot_of = {r.rid: s for s, r in enumerate(table.slots)
-                           if r is not None}
-            if self.health is not None:
+                n_active = int(table.active.sum())
+                t0 = rec.now() if rec else 0.0
+                with stage("decode.dispatch"):
+                    emitted, tok_lp, cur, cache, key, active, remaining = dispatch(
+                        ("decode",), self._sample_decode,
+                        self.params, cur, cache, key, self.ctx, temp, active, eos,
+                        remaining,
+                    )
+                clock += 1
+                stats.decode_dispatches += 1
+                stats.emitted_tokens += n_active
+                stats.active_slot_steps += n_active
+                stats.kv_byte_steps += alloc.pages_in_use * self._page_bytes
+                with stage("decode.wait"):
+                    jax.block_until_ready(emitted)
+                with stage("decode.fetch"):
+                    em = np.asarray(emitted)
+                    lp = np.asarray(tok_lp)
+                    ac = np.asarray(active)
+                t1 = rec.now() if rec else 0.0
                 msk = table.active  # the mask this dispatch computed under
-                self.health.observe_decode(
-                    0, clock=clock,
-                    mean_logprob=float(lp[msk].mean()) if msk.any() else None,
-                    alloc_failures=alloc.alloc_failures,
-                )
-            retired = table.record_step(em, lp, ac, clock, eos_id=eos_id)
-            if rec and retired:
-                t1 = rec.now()
-                for rid in retired:
-                    tracer.retired(table.outputs[rid], slot_of[rid], t1)
-                pool.sample()
-            if self.prober is not None and clock % self.probe_every == 0:
-                t0p = rec.now() if rec else 0.0
-                res = self.prober.probe(clock=clock)
-                stats.probe_dispatches += res.dispatches
-                if rec:
-                    rec.span("probe", proc="serve", track="health",
-                             t0=t0p, t1=rec.now(), args=res.as_dict())
-                    rec.count("probe.dispatches", res.dispatches)
-                self.health.observe_probe(0, res, clock=clock)
-                if self.alerts:
-                    self.alerts.evaluate(clock=clock)
+                with stage("record_step"):
+                    if rec:
+                        tracer.decode_dispatch(t0, t1, n_active=n_active, clock=clock)
+                        slot_of = {r.rid: s for s, r in enumerate(table.slots)
+                                   if r is not None}
+                    retired = table.record_step(em, lp, ac, clock, eos_id=eos_id)
+                    if rec and retired:
+                        t1 = rec.now()
+                        for rid in retired:
+                            tracer.retired(table.outputs[rid], slot_of[rid], t1)
+                        pool.sample()
+                if self.health is not None:
+                    with stage("health"):
+                        self.health.observe_decode(
+                            0, clock=clock,
+                            mean_logprob=float(lp[msk].mean()) if msk.any() else None,
+                            alloc_failures=alloc.alloc_failures,
+                        )
+                        if self.prober is not None and clock % self.probe_every == 0:
+                            t0p = rec.now() if rec else 0.0
+                            res = self.prober.probe(clock=clock)
+                            stats.probe_dispatches += res.dispatches
+                            if rec:
+                                rec.span("probe", proc="serve", track="health",
+                                         t0=t0p, t1=rec.now(), args=res.as_dict())
+                                rec.count("probe.dispatches", res.dispatches)
+                            self.health.observe_probe(0, res, clock=clock)
+                            if self.alerts:
+                                self.alerts.evaluate(clock=clock)
         stats.peak_resident_kv_bytes = max(
             stats.peak_resident_kv_bytes, alloc.peak_pages * self._page_bytes
         )

@@ -34,6 +34,7 @@ sub-population — see ``src/repro/fleet/README.md``.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -339,11 +340,13 @@ class PopulationFATEngine:
             if key not in self._fit_programs:
                 self._fit_programs[key] = self._make_fit(batch_fn, stacked.mode)
             t0 = self.obs.now() if self.obs else 0.0
-            trained = self._fit_programs[key](
-                params0, stacked.ok, jnp.asarray(chunk_budgets, jnp.int32)
-            )
+            with self._annotation("fit_chunk", lo):
+                trained = self._fit_programs[key](
+                    params0, stacked.ok, jnp.asarray(chunk_budgets, jnp.int32)
+                )
+                if self.obs:
+                    trained = jax.block_until_ready(trained)
             if self.obs:
-                trained = jax.block_until_ready(trained)
                 maxb = max(chunk_budgets) if chunk_budgets else 0
                 lane_steps = size * maxb  # padding lanes occupy real width
                 wasted = lane_steps - sum(chunk_budgets)
@@ -360,6 +363,14 @@ class PopulationFATEngine:
             self._record_fit_output(trained, keep, size)
             out.extend(_member_slice(trained, i) for i in range(keep))
         return out
+
+    def _annotation(self, name: str, lo: int):
+        """A profiler step annotation (numbered by the chunk's first member)
+        over a chunk's dispatch, so its recorder span has a twin on the
+        device trace's clock; nothing when no recorder is attached."""
+        if not self.obs:
+            return contextlib.nullcontext()
+        return jax.profiler.StepTraceAnnotation(name, step_num=lo)
 
     def _record_fit_output(self, trained, keep: int, width: int) -> None:
         """Hook on each raw (still member-stacked) fit-program output before
@@ -389,9 +400,10 @@ class PopulationFATEngine:
             if key not in self._steps_programs:
                 self._steps_programs[key] = self._make_steps(batch_fn, stacked.mode)
             t0 = self.obs.now() if self.obs else 0.0
-            crossed = np.asarray(
-                self._steps_programs[key](params0, stacked.ok, constraint, max_steps)
-            )
+            with self._annotation("probe_chunk", lo):
+                crossed = np.asarray(
+                    self._steps_programs[key](params0, stacked.ok, constraint, max_steps)
+                )
             if self.obs:
                 # Every lane runs until the slowest member crosses (or
                 # max_steps): realized lane-steps = width * max(realized).

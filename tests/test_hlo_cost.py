@@ -8,6 +8,8 @@ from repro.launch.hlo_cost import (
     entry_parameters,
     input_output_aliases,
     iter_instructions,
+    module_name,
+    scoped_instructions,
 )
 
 # A hand-written post-SPMD-style HLO module:
@@ -147,3 +149,40 @@ def test_real_cell_attribution_smollm():
     # form) * 4 executions (fwd + remat + 2 bwd dots share the label)
     analytic = 30 * 16 * 9 * 4096**2 * 64 * 2 * 4
     assert abs(qk - analytic) / analytic < 0.05
+
+
+# TPU-compiled HLO: layouts carry upper-case tiling/memory-space tags, and a
+# fusion carries its root's op_name
+_TPU_HLO = """
+HloModule jit_sample_decode, is_scheduled=true
+
+%fused_computation.8 (param_0.1: bf16[576,64], param_1.2: f32[4,4]) -> bf16[36864] {
+  %param_0.1 = bf16[576,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %gather.1 = bf16[576,64]{1,0:T(8,128)(2,1)} gather(%param_0.1), metadata={op_name="jit(sample_decode)/fault_mask/gather"}
+  ROOT %mul.1 = bf16[36864]{0:T(1024)(128)(2,1)} multiply(%gather.1), metadata={op_name="jit(sample_decode)/fault_mask/mul"}
+}
+
+ENTRY %main.2 (w: bf16[576,64], ok: f32[4,4], x: bf16[8,576]) -> bf16[8,64] {
+  %w = bf16[576,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %ok = f32[4,4]{1,0:T(4,128)} parameter(1)
+  %x = bf16[8,576]{1,0:T(8,128)(2,1)} parameter(2)
+  %fusion.8 = bf16[36864]{0:T(1024)(128)(2,1)S(1)} fusion(%w, %ok), kind=kCustom, calls=%fused_computation.8, metadata={op_name="jit(sample_decode)/fault_mask/gather"}
+  %reshape.3 = bf16[576,64]{1,0:T(8,128)(2,1)} reshape(%fusion.8), metadata={op_name="jit(sample_decode)/fault_mask_extra/reshape"}
+  ROOT %dot.4 = bf16[8,64]{1,0:T(8,128)(2,1)} dot(%x, %reshape.3), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(sample_decode)/dot_general"}
+}
+"""
+
+
+def test_opcodes_parse_past_tpu_layout_tags():
+    ops = {i.name: i.opcode for i in iter_instructions(_TPU_HLO)}
+    assert ops["fusion.8"] == "fusion" and ops["gather.1"] == "gather"
+    assert ops["dot.4"] == "dot" and ops["w"] == "parameter"
+
+
+def test_scoped_instructions_are_the_unfused_ops_under_the_scope():
+    assert module_name(_TPU_HLO) == "jit_sample_decode"
+    # the fused computation's gather and multiply are not ops of their own;
+    # a scope whose name only starts with the scope's is another scope
+    assert scoped_instructions(_TPU_HLO, "fault_mask") == ["fusion.8"]
+    assert scoped_instructions(_TPU_HLO, "dot_general") == ["dot.4"]
+    assert scoped_instructions(_TPU_HLO, "no_such_scope") == []

@@ -412,8 +412,190 @@ def test_recorder_histograms_cover_all_requests(served_model):
     assert m["serve.queue_wait_steps"]["count"] == len(reqs)
     assert m["serve.requests_retired"]["value"] == len(reqs)
     assert m["serve.tokens_emitted"]["value"] == stats.emitted_tokens
-    assert m["serve.decode_step_s"]["count"] == stats.decode_dispatches
+    steps = [e for e in rec.event_list() if e.kind == "span" and e.name == "decode_step"]
+    assert len(steps) == stats.decode_dispatches
     assert not math.isnan(m["serve.ttft_wall_s"]["p99"])
+
+
+# ---------------------------------------------------------------------------
+# round stages, queue spans, the mask's scope and the profiler's timeline
+# ---------------------------------------------------------------------------
+
+STAGES = ("serve_round", "schedule", "prefill.wait", "decode.dispatch",
+          "decode.wait", "decode.fetch", "record_step", "health")
+
+
+def _faulty_ctx(cfg, rate=0.1):
+    from repro.core import from_fault_map
+    from repro.core.faults import FaultMap
+
+    rng = np.random.default_rng(3)
+    return from_fault_map(FaultMap(rng.random((cfg.array_rows, cfg.array_cols)) < rate))
+
+
+def _staged_engine(cfg, params, recorder, ctx=None):
+    return ContinuousBatchingEngine(
+        cfg, params, ctx, num_slots=2, page_size=4, num_pages=32,
+        prefill_buckets=(8, 16), chunk_size=8, recorder=recorder, probe_every=2,
+    )
+
+
+def _host_spans(rec):
+    return sorted((e for e in rec.event_list() if e.kind == "span" and e.track == "host"),
+                  key=lambda e: (e.ts, -e.dur))
+
+
+def test_every_round_is_one_span_holding_its_stages(served_model):
+    cfg, params = served_model
+    rec = Recorder()
+    _, stats = _staged_engine(cfg, params, rec).serve(_trace_reqs(cfg))
+    host = _host_spans(rec)
+    assert {e.name for e in host} == set(STAGES)
+    rounds = [e for e in host if e.name == "serve_round"]
+    for a, b in zip(rounds, rounds[1:]):
+        assert a.ts + a.dur <= b.ts and a.args["clock"] < b.args["clock"]
+    per_round = [[] for _ in rounds]
+    starts = [r.ts for r in rounds]
+    for e in host:
+        if e.name == "serve_round":
+            continue
+        i = int(np.searchsorted(starts, e.ts, side="right")) - 1
+        assert i >= 0 and e.ts + e.dur <= rounds[i].ts + rounds[i].dur + 1e-12, e.name
+        per_round[i].append(e.name)
+    assert all(names.count("schedule") == 1 for names in per_round)
+    decoding = [names for names in per_round if "decode.dispatch" in names]
+    assert len(decoding) == stats.decode_dispatches
+    for names in decoding:
+        assert [n for n in names if n != "prefill.wait"] == [
+            "schedule", "decode.dispatch", "decode.wait", "decode.fetch", "record_step",
+            "health"]
+    assert sum(names.count("prefill.wait") for names in per_round) == stats.prefill_dispatches
+    # the harness's decode_step span holds its dispatch, wait and copies
+    steps = sorted((e for e in rec.event_list() if e.name == "decode_step"), key=lambda e: e.ts)
+    inner = [e for e in host if e.name in ("decode.dispatch", "decode.fetch")]
+    for step, (d, f) in zip(steps, zip(inner[::2], inner[1::2])):
+        assert step.ts <= d.ts and f.ts + f.dur <= step.ts + step.dur
+
+
+def test_each_request_waits_in_one_queue_span(served_model):
+    cfg, params = served_model
+    rec = Recorder()
+    outs, _ = _staged_engine(cfg, params, rec).serve(_trace_reqs(cfg))
+    evs = rec.event_list()
+    enq = {e.args["rid"]: e for e in evs if e.name == "enqueue"}
+    queue = [e for e in evs if e.name == "queue"]
+    assert sorted(e.args["rid"] for e in queue) == sorted(outs)
+    first = {}
+    for e in sorted((e for e in evs if e.name in ("admit", "chunk")), key=lambda e: e.ts):
+        first.setdefault(e.args["rid"], e)
+    for q in queue:
+        rid = q.args["rid"]
+        assert q.ts == enq[rid].ts
+        assert q.ts + q.dur == pytest.approx(first[rid].ts, abs=1e-12)
+        assert q.track == first[rid].track
+    assert first[3].name == "chunk"  # the chunked request's wait ends at its first chunk
+
+
+def _python_line(directory):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(directory / "**" / "*.xplane.pb"), recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if plane.name.startswith("/host:") and line.name.startswith("python"):
+                return [(e.name, e.start_ns, e.duration_ns) for e in line.events]
+    return []
+
+
+def test_stage_annotations_land_on_the_profilers_host_line(served_model, tmp_path):
+    cfg, params = served_model
+    rec = Recorder()
+    eng = _staged_engine(cfg, params, rec)
+    with jax.profiler.trace(str(tmp_path / "on")):
+        eng.serve(_trace_reqs(cfg))
+    marks = sorted((r for r in _python_line(tmp_path / "on") if r[0] in STAGES),
+                   key=lambda r: (r[1], -r[2]))
+    assert [r[0] for r in marks] == [e.name for e in _host_spans(rec)]
+    with jax.profiler.trace(str(tmp_path / "off")):
+        _staged_engine(cfg, params, None).serve(_trace_reqs(cfg))
+    assert not [r for r in _python_line(tmp_path / "off") if r[0] in STAGES]
+
+
+def test_fault_mask_scope_names_the_decode_programs_mask_ops(served_model):
+    cfg, params = served_model
+    faulty = _staged_engine(cfg, params, Recorder(), _faulty_ctx(cfg))
+    faulty.warmup()
+    hlo = faulty._aot[("decode",)].as_text()
+    assert "/fault_mask/" in hlo
+    ops = faulty.mask_ops["jit_sample_decode"]
+    assert ops and all(f"%{n} = " in hlo for n in ops)
+    faulty.serve(_trace_reqs(cfg)[:1])
+    (published,) = [e for e in faulty.obs.event_list() if e.name == "serve.programs"]
+    assert published.args["fault_mask"] == faulty.mask_ops
+    healthy = _staged_engine(cfg, params, None)
+    healthy.warmup()
+    assert "/fault_mask/" not in healthy._aot[("decode",)].as_text()
+    assert set(healthy.mask_ops) == set(faulty.mask_ops)
+    assert not any(healthy.mask_ops.values())
+
+
+def test_a_cached_build_with_other_scopes_is_not_loaded(served_model, tmp_path, monkeypatch):
+    """JAX keys its persistent compilation cache without op metadata: under
+    a fault mask the engine keys on it too, so it never loads an executable
+    built with other name scopes, whose mask ops it would not find."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.core import masking
+
+    cfg, params = served_model
+    ctx = _faulty_ctx(cfg)
+    settings = dict(jax_compilation_cache_dir=str(tmp_path),
+                    jax_persistent_cache_min_compile_time_secs=0,
+                    jax_persistent_cache_min_entry_size_bytes=0)
+    old = {k: getattr(jax.config, k) for k in settings}
+    try:
+        for k, v in settings.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        with monkeypatch.context() as mp:
+            mp.setattr(masking, "MASK_SCOPE", "another_build")
+            other = _staged_engine(cfg, params, None, ctx)
+            other.warmup()
+        assert not any(other.mask_ops.values()) and any(tmp_path.iterdir())
+        eng = _staged_engine(cfg, params, None, ctx)
+        eng.warmup()
+        assert eng.mask_ops["jit_sample_decode"]
+        assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_a_forced_aot_miss_records_one_compile_fallback(served_model):
+    cfg, params = served_model
+    rec = Recorder()
+    eng = _staged_engine(cfg, params, rec)
+    eng.warmup()
+    del eng._aot[("decode",)]
+    eng.serve(_trace_reqs(cfg))
+    (fb,) = [e for e in rec.event_list() if e.name == "compile.fallback"]
+    assert fb.args == dict(program="('decode',)", clock=0)
+    assert eng.compile_counts()["jit_fallback"] == 1
+
+
+def test_recorder_changes_zero_sampled_tokens_on_a_faulty_chip(served_model):
+    cfg, params = served_model
+    reqs = _trace_reqs(cfg)
+    ctx = _faulty_ctx(cfg)
+    off, _ = _staged_engine(cfg, params, None, ctx).serve(reqs)
+    on, _ = _staged_engine(cfg, params, Recorder(), ctx).serve(reqs)
+    assert set(off) == set(on)
+    for rid in off:
+        assert np.array_equal(off[rid].tokens, on[rid].tokens), rid
+        np.testing.assert_array_equal(off[rid].logprobs, on[rid].logprobs)
 
 
 # ---------------------------------------------------------------------------

@@ -171,6 +171,41 @@ def test_population_chunking_invariant(trainers, fleet):
             np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-6, atol=1e-6)
 
 
+def test_chunk_spans_have_profiler_step_annotations(trainers, fleet, tmp_path):
+    """Each fit/probe chunk's recorder span has a twin step annotation on
+    the profiler's host line; with no recorder, neither is recorded."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.obs import Recorder
+
+    pop, _ = trainers
+    ctxs = [from_fault_map(fm) for fm in fleet]
+
+    def run(recorder, where):
+        eng = make_fat_engine(
+            "population", loss_fn=pop.engine.loss_fn, opt_cfg=pop.opt_cfg,
+            eval_batches=pop._evals, eval_every=pop.eval_every, population_size=2,
+            recorder=recorder,
+        )
+        with jax.profiler.trace(str(where)):
+            eng.fit_batch(pop.base_params, ctxs, [4] * len(ctxs), pop._train_batch_fn)
+            eng.steps_to_constraint_batch(pop.base_params, ctxs, 2.0, 10, pop._probe_batch_fn)
+        (path,) = glob.glob(str(where / "**" / "*.xplane.pb"), recursive=True)
+        return [e.name for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:") for line in plane.lines
+                if line.name.startswith("python") for e in line.events
+                if e.name in ("fit_chunk", "probe_chunk")]
+
+    rec = Recorder()
+    marks = run(rec, tmp_path / "on")
+    spans = [e.name for e in rec.event_list() if e.name in ("fit_chunk", "probe_chunk")]
+    chunks = -(-len(ctxs) // 2)
+    assert sorted(marks) == sorted(spans) == ["fit_chunk"] * chunks + ["probe_chunk"] * chunks
+    assert run(None, tmp_path / "off") == []
+
+
 def test_measure_resilience_engines_agree(trainers):
     """Acceptance: both engines produce the SAME resilience table on
     identical seeds (identical fault-map grid, identical crossings)."""
