@@ -27,8 +27,9 @@ from repro.core.faults import FaultMap
 from repro.core.mapping import masked_weight
 
 # the name scope around each use-site mask (its construction and the
-# multiply, not the GEMM): the compiled program's ops under it are what the
-# serving engine publishes as its fault-mask ops (``mask_ops``)
+# multiply, not the GEMM) and around the serving engine's load-time premask:
+# a compiled program's ops under it are what the engine publishes as its
+# programs' fault-mask ops (``mask_ops``), none once the weights are premasked
 MASK_SCOPE = "fault_mask"
 
 __all__ = [
@@ -40,6 +41,7 @@ __all__ = [
     "from_fault_map",
     "stack_contexts",
     "context_leak_reason",
+    "is_array_mapped",
 ]
 
 
@@ -215,6 +217,13 @@ MASKABLE_KEYS = frozenset(
 )
 
 
+def is_array_mapped(path, leaf) -> bool:
+    """Whether a param leaf (at its ``tree_map_with_path`` path) runs as a
+    GEMM on the array: a weight of two or more dims under a MASKABLE_KEYS key."""
+    keys = {getattr(k, "key", None) for k in path}
+    return bool(keys & MASKABLE_KEYS) and getattr(leaf, "ndim", 0) >= 2
+
+
 def mask_selected_params(params: Any, ctx: FaultContext) -> Any:
     """Apply the FAP mask ONCE to every array-mapped weight leaf.
 
@@ -230,8 +239,7 @@ def mask_selected_params(params: Any, ctx: FaultContext) -> Any:
     _require_per_chip(ctx)
 
     def f(path, leaf):
-        keys = {getattr(k, "key", None) for k in path}
-        if keys & MASKABLE_KEYS and hasattr(leaf, "ndim") and leaf.ndim >= 2:
+        if is_array_mapped(path, leaf):
             return masked_weight(leaf, ctx.ok.astype(leaf.dtype))
         return leaf
 

@@ -361,10 +361,10 @@ def embed_inputs(cfg, params, batch: dict, ctx: FaultContext) -> tuple[Array, Ar
 
 
 def unembed(cfg, params, x: Array, ctx: FaultContext) -> Array:
-    if cfg.tie_embeddings:
-        logits = fault_linear(x, params["embed"].T, ctx)
-    else:
-        logits = fault_linear(x, params["lm_head"], ctx)
+    # a tied model reads ``embed.T`` unless its params carry an ``lm_head``
+    # beside it: the serving engine's pre-masked copy (serve/continuous.py)
+    w = params["lm_head"] if "lm_head" in params else params["embed"].T
+    logits = fault_linear(x, w, ctx)
     return shard_activation(logits, ("batch", "seq_carry", "vocab"))
 
 

@@ -37,6 +37,13 @@ Host/device split: sampling, masking and the paged read/write all live in
 the jitted steps; the host loop only moves tiny per-slot flags (emitted
 tokens, the active mask) to run admission/retirement between dispatches,
 plus the int32 pack/chunk index maps built by ``repro.serve.bucketing``.
+
+A serving chip's weights are frozen, so on a faulty chip the fault mask is
+applied once, not per use: at build (and on :meth:`set_silicon`) one small
+jitted program masks every array-mapped GEMM weight, and a tied model's
+``embed.T`` into an ``lm_head`` beside the raw ``embed`` the lookup reads.
+The serving programs then run on those weights with a context that masks
+nothing, so none of them holds a mask op.
 """
 from __future__ import annotations
 
@@ -49,7 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.masking import MASK_SCOPE, FaultContext, healthy
+from repro.core.mapping import masked_weight
+from repro.core.masking import MASK_SCOPE, FaultContext, healthy, is_array_mapped
 from repro.launch.hlo_cost import module_name, scoped_instructions
 from repro.models import model as M
 from repro.obs.alerts import AlertEngine, AlertRule
@@ -334,7 +342,9 @@ class ContinuousBatchingEngine:
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.cfg = cfg
+        # the weights as given: the prober's and every premask's source
         self.params = params
+        # the LIVE fault context: set_silicon's checks and the prober read it
         self.ctx = ctx or healthy()
         self.num_slots = num_slots
         self.page_size = page_size
@@ -377,13 +387,20 @@ class ContinuousBatchingEngine:
         self._prefill_chunk = jax.jit(
             self._prefill_chunk_fn, donate_argnums=(3, 4, 5, 6)
         )
+        # what the serving programs run on: on a faulty chip the premasked
+        # weights and a context that masks nothing; on a healthy chip the
+        # params as given
+        self._serve_ctx = healthy()
+        self._premask = jax.jit(self._premask_fn)
+        self.served_params = self._premasked() if self.ctx.active else params
         # AOT-compiled executables by program key — see warmup(); dispatch
         # prefers these, falling back to the jit wrappers above (whose
         # _cache_size() then counts traffic-time compiles)
         self._aot: dict = {}
         self.used_programs: set = set()
         # HLO module name -> names of its ops under the fault-mask scope
-        # (core/masking.py), from the AOT programs; see warmup()
+        # (core/masking.py), from the AOT programs: empty lists, the mask
+        # being applied at load; see warmup()
         self.mask_ops: dict[str, list[str]] = {}
         # fault detection (ROADMAP item 2): an ABFT prober dispatched every
         # probe_every decode dispatches, feeding the health state machine
@@ -430,19 +447,21 @@ class ContinuousBatchingEngine:
 
     def set_silicon(self, ctx: FaultContext) -> None:
         """Simulate a mid-flight silicon change: swap the LIVE fault context
-        every subsequent dispatch (decode, prefill, probes) computes
-        through, WITHOUT rebasing the prober's golden snapshots — so the
+        and re-mask the served weights from the raw ones under it (the same
+        premask program at the same shapes, so nothing compiles), so every
+        subsequent dispatch (decode, prefill, probes) computes through the
+        new map, WITHOUT rebasing the prober's golden snapshots — so the
         next probe sees the divergence. The engine must have been built
         with an ACTIVE context of the same mask shape (a zero-fault
-        ``FaultMap`` context models pristine silicon): the AOT executables
-        were compiled for that pytree structure and an ok=None ↔ ok=array
-        flip would be a different program."""
+        ``FaultMap`` context models pristine silicon): a healthy engine
+        serves the raw params, and its AOT executables were compiled for
+        their pytree structure, which has no tied ``lm_head``."""
         cur = self.ctx
         if cur.ok is None or ctx is None or ctx.ok is None:
             raise ValueError(
                 "set_silicon needs ACTIVE fault contexts on both sides; "
                 "construct the engine with an explicit (possibly zero-fault)"
-                " FaultMap context so the mask is a live program input"
+                " FaultMap context so its served weights are premasked"
             )
         if cur.mode != ctx.mode or tuple(cur.ok.shape) != tuple(ctx.ok.shape):
             raise ValueError(
@@ -451,8 +470,38 @@ class ContinuousBatchingEngine:
                 f"got {ctx.mode}/{tuple(ctx.ok.shape)}"
             )
         self.ctx = ctx
+        self.served_params = self._premasked()
 
     # -- jitted pieces ------------------------------------------------------
+
+    def _premask_fn(self, gemm, embed, ok):
+        """The served weights under healthy-PE mask ``ok``: each GEMM weight
+        times its periodic mask, in its own dtype, and — for a tied model —
+        the masked ``embed.T`` that ``M.unembed`` reads as ``lm_head``."""
+        with jax.named_scope(MASK_SCOPE):
+            gemm = [masked_weight(w, ok) for w in gemm]
+            return gemm, None if embed is None else masked_weight(embed.T, ok)
+
+    def _premasked(self):
+        """Run the premask program under the live context; the served params
+        share every other leaf (the embedding the lookup reads, the norms)
+        with the raw ones."""
+        rec = self.obs
+        flat, treedef = jax.tree_util.tree_flatten_with_path(self.params)
+        ix = [i for i, (path, w) in enumerate(flat) if is_array_mapped(path, w)]
+        embed = self.params["embed"] if self.cfg.tie_embeddings else None
+        with rec.timed("premask", proc="serve", track="host",
+                       annotate=jax.profiler.TraceAnnotation):
+            # one mask dtype, so a set_silicon map of another reuses the program
+            gemm, head = self._premask(
+                [flat[i][1] for i in ix], embed, jnp.asarray(self.ctx.ok, jnp.float32))
+            jax.block_until_ready((gemm, head))
+        rec.count("fault_mask.premask")
+        leaves = [w for _, w in flat]
+        for i, w in zip(ix, gemm):
+            leaves[i] = w
+        served = jax.tree_util.tree_unflatten(treedef, leaves)
+        return served if head is None else {**served, "lm_head": head}
 
     def _packed_admit_fn(
         self, params, tokens, positions, segments, ctx, cache, cur, active,
@@ -544,20 +593,11 @@ class ContinuousBatchingEngine:
         name to its fault-mask ops (``mask_ops``), which ``serve()``
         publishes so that a device trace's mask time can be told apart
         (programs that share a module name, the bucket ladder's, share one
-        list). Returns the AOT program count."""
+        list): none, on a healthy chip and on a premasked faulty one alike.
+        Returns the AOT program count."""
         if self.prefill_buckets is None:
             raise ValueError("warmup() needs bucketed prefill; prefill_buckets is None")
-        # mask_ops reads the programs' op metadata back, which JAX leaves out
-        # of its persistent compilation cache's key: a build that differs
-        # from a cached one only in its name scopes would load that one's
-        # executable. Under a fault mask, key on the metadata too.
-        keyed = jax.config.jax_compilation_cache_include_metadata_in_key
-        jax.config.update("jax_compilation_cache_include_metadata_in_key",
-                          keyed or self.ctx.active)
-        try:
-            self._compile_programs()
-        finally:
-            jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
+        self._compile_programs()
         mask_ops: dict = {}
         for exe in self._aot.values():
             hlo = exe.as_text()
@@ -567,8 +607,8 @@ class ContinuousBatchingEngine:
         return len(self._aot)
 
     def _compile_programs(self) -> None:
-        params_s = shape_structs(self.params)
-        ctx_s = shape_structs(self.ctx)
+        params_s = shape_structs(self.served_params)
+        ctx_s = shape_structs(self._serve_ctx)
         cache, cur, active, remaining = self._state_structs()
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
         K, maxp = self.max_pack, self.max_pages_per_seq
@@ -691,8 +731,8 @@ class ContinuousBatchingEngine:
             t0 = rec.now() if rec else 0.0
             cache, cur, active, remaining = dispatch(
                 ("prefill_admit", width), self._packed_admit,
-                self.params, arrays["tokens"], arrays["positions"],
-                arrays["segments"], self.ctx, cache, cur, active, remaining,
+                self.served_params, arrays["tokens"], arrays["positions"],
+                arrays["segments"], self._serve_ctx, cache, cur, active, remaining,
                 arrays["page_ix"], arrays["page_off"], arrays["gather_pos"],
                 arrays["slots"], arrays["rows"], arrays["seq_lens"],
                 arrays["budgets"],
@@ -726,7 +766,7 @@ class ContinuousBatchingEngine:
                 t0 = rec.now() if rec else 0.0
                 cache, cur, active, remaining = dispatch(
                     ("prefill_chunk", st.size), self._prefill_chunk,
-                    self.params, ct[None], self.ctx, cache, cur, active,
+                    self.served_params, ct[None], self._serve_ctx, cache, cur, active,
                     remaining, np.int32(slot), row, maps["page_ix"],
                     maps["page_off"], np.int32(st.start), np.int32(st.valid),
                     np.int32(r.max_new_tokens), np.bool_(st.final),
@@ -800,7 +840,7 @@ class ContinuousBatchingEngine:
                 with stage("decode.dispatch"):
                     emitted, tok_lp, cur, cache, key, active, remaining = dispatch(
                         ("decode",), self._sample_decode,
-                        self.params, cur, cache, key, self.ctx, temp, active, eos,
+                        self.served_params, cur, cache, key, self._serve_ctx, temp, active, eos,
                         remaining,
                     )
                 clock += 1

@@ -524,16 +524,25 @@ def test_stage_annotations_land_on_the_profilers_host_line(served_model, tmp_pat
 
 
 def test_fault_mask_scope_names_the_decode_programs_mask_ops(served_model):
+    """A faulty chip's weights are masked once, by the premask program: no
+    serving program holds an op under the fault-mask scope, and the
+    published map still names every program, each with an empty list, as on
+    a healthy chip."""
+    from repro.core.masking import is_array_mapped
+
     cfg, params = served_model
     faulty = _staged_engine(cfg, params, Recorder(), _faulty_ctx(cfg))
     faulty.warmup()
-    hlo = faulty._aot[("decode",)].as_text()
-    assert "/fault_mask/" in hlo
-    ops = faulty.mask_ops["jit_sample_decode"]
-    assert ops and all(f"%{n} = " in hlo for n in ops)
+    assert all("/fault_mask/" not in exe.as_text() for exe in faulty._aot.values())
+    assert "jit_sample_decode" in faulty.mask_ops
+    assert not any(faulty.mask_ops.values())
     faulty.serve(_trace_reqs(cfg)[:1])
     (published,) = [e for e in faulty.obs.event_list() if e.name == "serve.programs"]
     assert published.args["fault_mask"] == faulty.mask_ops
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    gemm = [w for path, w in flat if is_array_mapped(path, w)]
+    premask = faulty._premask.lower(gemm, params["embed"], faulty.ctx.ok).compile()
+    assert "/fault_mask/" in premask.as_text()
     healthy = _staged_engine(cfg, params, None)
     healthy.warmup()
     assert "/fault_mask/" not in healthy._aot[("decode",)].as_text()
@@ -541,37 +550,26 @@ def test_fault_mask_scope_names_the_decode_programs_mask_ops(served_model):
     assert not any(healthy.mask_ops.values())
 
 
-def test_a_cached_build_with_other_scopes_is_not_loaded(served_model, tmp_path, monkeypatch):
-    """JAX keys its persistent compilation cache without op metadata: under
-    a fault mask the engine keys on it too, so it never loads an executable
-    built with other name scopes, whose mask ops it would not find."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    from repro.core import masking
-
+def test_a_premask_is_counted_at_build_and_on_set_silicon(served_model):
+    """Each premask counts once and is a span on the host track: one at
+    build, one per set_silicon, the second through the program the first
+    compiled; a healthy engine premasks nothing."""
     cfg, params = served_model
-    ctx = _faulty_ctx(cfg)
-    settings = dict(jax_compilation_cache_dir=str(tmp_path),
-                    jax_persistent_cache_min_compile_time_secs=0,
-                    jax_persistent_cache_min_entry_size_bytes=0)
-    old = {k: getattr(jax.config, k) for k in settings}
-    try:
-        for k, v in settings.items():
-            jax.config.update(k, v)
-        cc.reset_cache()
-        with monkeypatch.context() as mp:
-            mp.setattr(masking, "MASK_SCOPE", "another_build")
-            other = _staged_engine(cfg, params, None, ctx)
-            other.warmup()
-        assert not any(other.mask_ops.values()) and any(tmp_path.iterdir())
-        eng = _staged_engine(cfg, params, None, ctx)
-        eng.warmup()
-        assert eng.mask_ops["jit_sample_decode"]
-        assert not jax.config.jax_compilation_cache_include_metadata_in_key
-    finally:
-        for k, v in old.items():
-            jax.config.update(k, v)
-        cc.reset_cache()
+    rec = Recorder()
+    eng = _staged_engine(cfg, params, rec, _faulty_ctx(cfg))
+    assert rec.metrics.counter("fault_mask.premask").value == 1
+    eng.warmup()
+    eng.set_silicon(_faulty_ctx(cfg, rate=0.2))
+    assert rec.metrics.counter("fault_mask.premask").value == 2
+    spans = [e for e in rec.event_list() if e.name == "premask"]
+    assert len(spans) == 2 and all(e.kind == "span" and e.track == "host" for e in spans)
+    assert eng._premask._cache_size() == 1
+    eng.serve(_trace_reqs(cfg)[:2])
+    assert eng.compile_counts()["jit_fallback"] == 0
+    quiet = Recorder()
+    _staged_engine(cfg, params, quiet)
+    assert quiet.metrics.counter("fault_mask.premask").value == 0
+    assert not [e for e in quiet.event_list() if e.name == "premask"]
 
 
 def test_a_forced_aot_miss_records_one_compile_fallback(served_model):

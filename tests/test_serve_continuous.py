@@ -446,6 +446,83 @@ def test_continuous_faulty_chip_differs(served_model):
     assert not np.array_equal(healthy_out[0].tokens, faulty_out[0].tokens)
 
 
+def _mixed_stream(cfg, late: int):
+    """Packed, bucketed and chunked prompts, at clock 0 and at ``late``."""
+    return [
+        Request(0, _prompt(cfg, 110, 6), max_new_tokens=3),
+        Request(1, _prompt(cfg, 111, 5), max_new_tokens=4),
+        Request(2, _prompt(cfg, 112, 13), max_new_tokens=7),
+        Request(3, _prompt(cfg, 113, 4), max_new_tokens=5, arrival=late),
+        Request(4, _prompt(cfg, 114, 3), max_new_tokens=4, arrival=late),
+        Request(5, _prompt(cfg, 115, 30), max_new_tokens=4, arrival=late),
+    ]
+
+
+def _premask_engine(cfg, params, ctx):
+    return ContinuousBatchingEngine(
+        cfg, params, ctx, num_slots=3, page_size=4, num_pages=64,
+        prefill_buckets=(8, 16), chunk_size=8, max_pack=2,
+    )
+
+
+@pytest.mark.parametrize("switch_at", [None, 4], ids=["at_build", "on_set_silicon"])
+def test_premasked_engine_serves_the_per_use_masked_tokens(served_model, switch_at):
+    """A faulty engine masks its frozen weights once — at build, and again
+    on a mid-serve set_silicon — and serves what masking inside every GEMM
+    serves: each request admitted under a map, packed, bucketed or chunked,
+    matches a per-use masked ServeEngine run under that map, and the weights
+    a swap leaves are those a fresh engine built with the new map serves."""
+    cfg, params = served_model
+    R, C = cfg.array_rows, cfg.array_cols
+    old = from_fault_map(random_fault_map(5, R, C, 0.2))
+    new = from_fault_map(random_fault_map(6, R, C, 0.3))
+    eng = _premask_engine(cfg, params, old)
+    eng.warmup()
+
+    def on_step(clock):
+        if clock == switch_at:
+            eng.set_silicon(new)
+
+    reqs = _mixed_stream(cfg, late=4)
+    outs, stats = eng.serve(reqs, on_step=on_step)
+    assert stats.chunk_dispatches and stats.prefill_dispatches > stats.chunk_dispatches + 1
+    assert eng.compile_counts()["jit_fallback"] == 0
+    refs = {id(old): ServeEngine(cfg, params, old, max_len=None, page_size=4),
+            id(new): ServeEngine(cfg, params, new, max_len=None, page_size=4)}
+    under = {}
+    for r in reqs:
+        o = outs[r.rid]
+        if switch_at is None or o.finished_step <= switch_at:
+            under[r.rid] = old
+        elif o.admitted_step >= switch_at:
+            under[r.rid] = new
+    # on a swap: 0 and 1 finish under the old map, 2 spans both, and the
+    # late arrivals (a pack of 3 and 4, then 5 in chunks) run under the new
+    assert {rid for rid, c in under.items() if c is new} == (
+        set() if switch_at is None else {3, 4, 5})
+    assert len(under) == (6 if switch_at is None else 5)
+    for rid, ctx in under.items():
+        r = reqs[rid]
+        res = refs[id(ctx)].generate(jnp.asarray(r.tokens)[None], max_new_tokens=r.max_new_tokens)
+        assert np.array_equal(outs[rid].tokens, np.asarray(res.tokens[0, len(r.tokens):])), rid
+        np.testing.assert_allclose(outs[rid].logprobs, np.asarray(res.logprobs[0]),
+                                   rtol=0, atol=1e-5)
+    if switch_at is not None:
+        fresh = _premask_engine(cfg, params, new).served_params
+        jax.tree_util.tree_map(np.testing.assert_array_equal, eng.served_params, fresh)
+
+
+def test_a_healthy_engine_serves_its_params_unmasked(served_model):
+    """No fault context, no premask: the served params are the params the
+    engine was given, and its premask program is never compiled."""
+    cfg, params = served_model
+    eng = _premask_engine(cfg, params, None)
+    assert eng.served_params is params
+    assert eng._premask._cache_size() == 0
+    outs, _ = eng.serve(_mixed_stream(cfg, late=2))
+    assert len(outs) == 6 and eng._premask._cache_size() == 0
+
+
 # ---------------------------------------------------------------------------
 # EOS semantics: static (masked) and continuous (retiring) engines agree
 # ---------------------------------------------------------------------------
