@@ -359,6 +359,7 @@ def mamba_scan_launch(
             ((1, bl_, n), dtype, True, bc),  # B block
             ((1, bl_, n), dtype, True, bc),  # C block
             ((1, bd_), dtype, True, (1, dim_p)),  # D skip
+            ((1, bd_, n), jnp.float32, True, (batch, dim_p, n)),  # h0 in
             ((1, bl_, bd_), dtype, True, seq),  # y out
             ((1, bd_, n), jnp.float32, True, (batch, dim_p, n)),  # h_last out
             ((bd_, n), jnp.float32, False),  # h scratch

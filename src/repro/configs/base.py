@@ -52,7 +52,7 @@ class ArchConfig:
     """
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | vlm | audio
+    family: str  # dense | moe | ssm | hybrid | interleaved | vlm | audio
     num_layers: int
     d_model: int
     d_ff: int
@@ -65,6 +65,7 @@ class ArchConfig:
     qk_norm: bool = False
     sliding_window: Optional[int] = None  # SWA window size (tokens)
     rope_theta: float = 10_000.0
+    use_rope: bool = True  # False: attention with no positional encoding (Jamba)
 
     # --- MoE ---
     num_experts: int = 0
@@ -75,6 +76,13 @@ class ArchConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0  # 0 -> ceil(d_model / 16)
+    ssm_inner_norm: bool = False  # RMSNorm on the mixer's dt, B and C streams (Jamba)
+
+    # --- interleaved stack (Jamba): layer i is attention iff
+    # i % attn_layer_period == attn_layer_offset, every other layer Mamba-1;
+    # each layer has its own MLP ---
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
 
     # --- structure ---
     is_encoder: bool = False  # encoder-only (no causal mask, no decode)
@@ -116,11 +124,30 @@ class ArchConfig:
 
     @property
     def has_attention(self) -> bool:
-        return self.family in ("dense", "moe", "vlm", "audio", "hybrid")
+        return self.family in ("dense", "moe", "vlm", "audio", "hybrid", "interleaved")
 
     @property
     def has_ssm(self) -> bool:
-        return self.family in ("ssm", "hybrid")
+        return self.family in ("ssm", "hybrid", "interleaved")
+
+    def is_attention_layer(self, i: int) -> bool:
+        """Whether layer ``i`` attends: every layer of an attention family,
+        one in ``attn_layer_period`` of an interleaved stack, none of an SSM."""
+        if self.family == "interleaved":
+            return i % self.attn_layer_period == self.attn_layer_offset
+        return self.has_attention
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers that hold keys and values: the paged pool's depth."""
+        return sum(self.is_attention_layer(i) for i in range(self.num_layers))
+
+    @property
+    def num_ssm_layers(self) -> int:
+        """Layers that carry recurrent (conv, SSM) state."""
+        if self.family == "interleaved":
+            return self.num_layers - self.num_attn_layers
+        return self.num_layers if self.has_ssm else 0
 
     @property
     def has_moe(self) -> bool:
@@ -138,30 +165,34 @@ class ArchConfig:
         """Analytic parameter count (used for roofline 6ND and FSDP policy)."""
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         hd = self.resolved_head_dim
-        per_layer = 0
+        attn = ssm = ffn = 0
         if self.has_attention:
-            q = d * self.num_heads * hd
-            kv = 2 * d * self.num_kv_heads * hd
-            o = self.num_heads * hd * d
-            per_layer += q + kv + o
+            attn = 2 * d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd  # q, o; k, v
         if self.has_ssm:
             di, n, r = self.d_inner, self.ssm_state, self.resolved_dt_rank
-            per_layer += d * 2 * di  # in_proj (x and z branches)
-            per_layer += di * self.ssm_conv  # depthwise conv
-            per_layer += di * (r + 2 * n)  # x_proj -> dt, B, C
-            per_layer += r * di + di  # dt_proj
-            per_layer += di * n + di  # A_log, D
-            per_layer += di * d  # out_proj
+            ssm += d * 2 * di  # in_proj (x and z branches)
+            ssm += di * self.ssm_conv + di  # depthwise conv and its bias
+            ssm += di * (r + 2 * n)  # x_proj -> dt, B, C
+            ssm += r * di + di  # dt_proj
+            ssm += di * n + di  # A_log, D
+            ssm += di * d  # out_proj
+            if self.ssm_inner_norm:
+                ssm += r + 2 * n  # dt, B and C norms
         if self.has_moe:
-            per_layer += d * self.num_experts  # router
-            per_layer += self.num_experts * 3 * d * f  # gate/up/down per expert
+            ffn += d * self.num_experts  # router
+            ffn += self.num_experts * 3 * d * f  # gate/up/down per expert
         elif f > 0:
             n_mats = 3 if self.activation == "swiglu" else 2
-            per_layer += n_mats * d * f
-        per_layer += 2 * d  # two norms
+            ffn += n_mats * d * f
+        norms = 2 * d
+        if self.family == "interleaved":
+            n_attn = self.num_attn_layers
+            layers = n_attn * attn + (L - n_attn) * ssm + L * (ffn + norms)
+        else:
+            layers = L * (attn + ssm + ffn + norms)
         emb = v * d
         head = 0 if self.tie_embeddings else v * d
-        return L * per_layer + emb + head + d  # final norm
+        return layers + emb + head + d  # final norm
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed experts)."""
@@ -187,6 +218,7 @@ _ARCH_MODULES = [
     "internvl2_26b",
     "hubert_xlarge",
     "hymba_1_5b",
+    "jamba2_3b",
     "paper_mlp",
 ]
 
@@ -284,6 +316,9 @@ def reduce_config(cfg: ArchConfig) -> ArchConfig:
         changes.update(num_experts=4, experts_per_token=min(cfg.experts_per_token, 2))
     if cfg.has_ssm:
         changes.update(ssm_state=8, ssm_conv=4, ssm_expand=2, ssm_dt_rank=8)
+    if cfg.family == "interleaved":
+        # one period, attention between two Mamba runs
+        changes.update(num_layers=3, attn_layer_period=3, attn_layer_offset=1)
     if cfg.sliding_window:
         changes["sliding_window"] = 32
     return replace(cfg, **changes)

@@ -440,7 +440,7 @@ class ShardedFleetServeEngine:
         """Chip-indexed twin of ``ContinuousBatchingEngine._prefill_chunk_fn``:
         one fixed-size chunk of a long prompt streaming into one chip's page
         chain; the final chunk (``activate``) flips the slot live."""
-        logits, kc, vc = M.prefill_chunk(
+        logits, kc, vc, _ = M.prefill_chunk(
             params_c, tokens, self.cfg, ctx_c,
             k_pages=cache["k_pages"][chip], v_pages=cache["v_pages"][chip],
             row=row, prefix_len=prefix, valid_len=valid,

@@ -3,12 +3,13 @@
 Naive XLA lowering either materializes (B, L, D, N) intermediates (HBM
 disaster) or runs an L-step scan with per-step HBM round-trips. The TPU
 rethink: grid (B, D/bd, L/bl) with L innermost; the running state h (bd, N)
+starts from the given ``h0`` (a chunk continues the one before it) and
 lives in VMEM scratch across the whole L sweep, each grid step streams one
 (bl, bd) chunk of u/dt and (bl, N) of B/C through VMEM, runs the recurrence
 sequentially in-register (VPU) one sublane tile of timesteps at a time, and
 writes the (bl, bd) output chunk. HBM
-traffic is exactly one read of the inputs + one write of y — the roofline
-floor for this bandwidth-bound op.
+traffic is exactly one read of the inputs and ``h0`` + one write of y and
+the final state — the roofline floor for this bandwidth-bound op.
 """
 from __future__ import annotations
 
@@ -23,14 +24,14 @@ from repro.kernels.common import grid_for, resolve_interpret, tpu_compiler_param
 
 
 def _kernel(
-    u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hlast_ref, h_ref,
+    u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref, hlast_ref, h_ref,
     *, bl: int, nl: int, rows: int,
 ):
     il = pl.program_id(2)
 
     @pl.when(il == 0)
     def _init():
-        h_ref[...] = jnp.zeros_like(h_ref)
+        h_ref[...] = h0_ref[0]
 
     a = a_ref[...].astype(jnp.float32)  # (bd, N)
     dskip = d_ref[...].astype(jnp.float32)  # (1, bd)
@@ -82,6 +83,7 @@ def selective_scan_pallas(
     b: jax.Array,  # (B, L, N)
     c: jax.Array,  # (B, L, N)
     d: jax.Array,  # (D,)
+    h0: jax.Array | None = None,  # (B, D, N) state to continue from; zeros
     *,
     bd: int = 256,
     bl: int = 128,
@@ -99,6 +101,8 @@ def selective_scan_pallas(
     (nd, nl) = grid_for((dim, length), (bd, bl))
     grid = (bsz, nd, nl)
     d2 = d.reshape(1, dim)
+    if h0 is None:
+        h0 = jnp.zeros((bsz, dim, n), jnp.float32)
 
     kernel = functools.partial(_kernel, bl=bl, nl=nl, rows=rows)
     y, hlast = pl.pallas_call(
@@ -111,6 +115,7 @@ def selective_scan_pallas(
             pl.BlockSpec((1, bl, n), lambda ib, id_, il: (ib, il, 0)),  # b
             pl.BlockSpec((1, bl, n), lambda ib, id_, il: (ib, il, 0)),  # c
             pl.BlockSpec((1, bd), lambda ib, id_, il: (0, id_)),  # d skip
+            pl.BlockSpec((1, bd, n), lambda ib, id_, il: (ib, id_, 0)),  # h0
         ],
         out_specs=[
             pl.BlockSpec((1, bl, bd), lambda ib, id_, il: (ib, il, id_)),
@@ -125,5 +130,5 @@ def selective_scan_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(u, dt, a, b, c, d2)
+    )(u, dt, a, b, c, d2, h0.astype(jnp.float32))
     return y, hlast

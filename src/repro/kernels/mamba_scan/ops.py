@@ -6,9 +6,12 @@ Non-block-multiple (L, D) shapes are zero-padded up to block multiples:
 padded steps carry ``u = dt = 0`` so the recurrence is inert there
 (``h <- exp(0 * A) * h + 0 = h``) and padded channels are sliced off the
 outputs — the wrapper used to silently fall back to whole-axis blocks
-instead, losing the chunked VMEM schedule."""
-from __future__ import annotations
+instead, losing the chunked VMEM schedule.
 
+``h0`` (``(B, D, N)``, default zeros) is the state the scan continues
+from: a prompt streamed in chunks carries each chunk's final state into
+the next, and splitting a scan anywhere reproduces the unsplit one."""
+from __future__ import annotations
 
 from repro.kernels.common import is_tpu_backend, pad_axes_to, pad_to_multiple, tuned_block
 from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas, sublane_rows
@@ -16,13 +19,14 @@ from repro.kernels.mamba_scan.ref import selective_scan_ref, selective_step_ref
 
 
 def selective_scan(
-    u, dt, a, b, c, d, *, bd: int | None = None, bl: int | None = None, interpret=None
+    u, dt, a, b, c, d, h0=None, *, bd: int | None = None, bl: int | None = None,
+    interpret=None,
 ):
     """``bd``/``bl`` default to the tuning cache's winner for this launch
     when one exists, else the 256/128 heuristics (``tuned_block`` seam)."""
     if interpret is None:
         if not is_tpu_backend():
-            return selective_scan_ref(u, dt, a, b, c, d)
+            return selective_scan_ref(u, dt, a, b, c, d, h0)
         interpret = False
     bsz, length, dim = u.shape
     blocks = tuned_block(
@@ -44,8 +48,9 @@ def selective_scan(
     bp = pad_axes_to(b, {1: len_p})
     cp = pad_axes_to(c, {1: len_p})
     dp = pad_axes_to(d, {0: dim_p})
+    h0p = None if h0 is None else pad_axes_to(h0, {1: dim_p})
     y, hlast = selective_scan_pallas(
-        up, dtp, ap, bp, cp, dp, bd=bd_, bl=bl_, interpret=interpret
+        up, dtp, ap, bp, cp, dp, h0p, bd=bd_, bl=bl_, interpret=interpret
     )
     return y[:, :length, :dim], hlast[:, :dim]
 

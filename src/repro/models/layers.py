@@ -348,7 +348,7 @@ def attention_block(
     q = jnp.moveaxis(q, 1, 2)  # (B, H, S, D)
     k = jnp.moveaxis(k, 1, 2)
     v = jnp.moveaxis(v, 1, 2)
-    if not cfg.is_encoder:
+    if cfg.use_rope and not cfg.is_encoder:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     q = shard_activation(q, ("batch", "heads", "seq", None))

@@ -1,12 +1,16 @@
 """Model assembly: init, forward (train/prefill), decode, loss.
 
 One generic scan-over-layers transformer covering all assigned families:
-dense / moe / ssm (mamba) / hybrid (parallel attn+ssm) / vlm / audio.
+dense / moe / ssm (mamba) / hybrid (parallel attn+ssm) / interleaved
+(Jamba: Mamba layers and attention layers in one stack) / vlm / audio.
 Per-layer params are stacked on a leading 'layers' dim and consumed by
 ``jax.lax.scan`` (compact HLO — one lowered block regardless of depth) with
-a configurable remat policy. Every parameterized GEMM goes through
-``fault_linear`` so the chip's FaultContext masks exactly the weights the
-systolic mapping places on faulty PEs.
+a configurable remat policy. An interleaved stack keeps one stack per kind
+of layer (``layers`` for the attention layers, ``mamba_layers``) and runs
+one scan per run of consecutive layers of one kind (:func:`layer_runs`).
+Every parameterized GEMM goes through ``fault_linear`` so the chip's
+FaultContext masks exactly the weights the systolic mapping places on
+faulty PEs.
 """
 from __future__ import annotations
 
@@ -112,7 +116,7 @@ def _init_moe(cfg, key):
 
 
 def _ssm_specs(cfg):
-    return dict(
+    s = dict(
         in_proj=("embed", "inner"),
         conv_w=(None, "inner"),
         conv_b=("inner",),
@@ -123,6 +127,9 @@ def _ssm_specs(cfg):
         d_skip=("inner",),
         out_proj=("inner", "embed"),
     )
+    if cfg.ssm_inner_norm:
+        s.update(dt_norm=(None,), b_norm=(None,), c_norm=(None,))
+    return s
 
 
 def _init_ssm(cfg, key):
@@ -144,6 +151,8 @@ def _init_ssm(cfg, key):
         d_skip=jnp.ones((di,)),
         out_proj=_dense(ks[5], (di, d)),
     )
+    if cfg.ssm_inner_norm:
+        p.update(dt_norm=jnp.ones((r,)), b_norm=jnp.ones((n,)), c_norm=jnp.ones((n,)))
     return p, _ssm_specs(cfg)
 
 
@@ -161,10 +170,14 @@ def _norm_param(cfg):
     return p, _norm_specs(cfg)
 
 
+MAMBA_STACK = "mamba_layers"  # params key of an interleaved stack's Mamba layers
+
+
 def layer_specs(cfg) -> dict:
-    """Logical-axes tree of one (unstacked) layer — no allocation."""
+    """Logical-axes tree of one (unstacked) layer — no allocation. For an
+    interleaved stack, one of its attention layers."""
     s: dict = {"ln1": _norm_specs(cfg)}
-    if cfg.family in ("dense", "moe", "vlm", "audio", "hybrid"):
+    if cfg.has_attention:
         s["attn"] = _attn_specs(cfg)
     if cfg.family == "hybrid":
         s["ssm"] = _ssm_specs(cfg)
@@ -175,17 +188,23 @@ def layer_specs(cfg) -> dict:
     if cfg.family == "moe":
         s["ln2"] = _norm_specs(cfg)
         s["moe"] = _moe_specs(cfg)
-    elif cfg.family in ("dense", "vlm", "audio", "hybrid"):
+    elif cfg.has_attention:
         s["ln2"] = _norm_specs(cfg)
         s["mlp"] = _mlp_specs(cfg)
     return s
+
+
+def mamba_layer_specs(cfg) -> dict:
+    """Logical-axes tree of one Mamba layer of an interleaved stack."""
+    return dict(ln1=_norm_specs(cfg), ssm=_ssm_specs(cfg), ln2=_norm_specs(cfg),
+                mlp=_mlp_specs(cfg))
 
 
 def _init_layer(cfg, key):
     ks = jax.random.split(key, 4)
     p = {}
     p["ln1"], _ = _norm_param(cfg)
-    if cfg.family in ("dense", "moe", "vlm", "audio", "hybrid"):
+    if cfg.has_attention:
         p["attn"], _ = _init_attn(cfg, ks[0])
     if cfg.family == "hybrid":
         p["ssm"], _ = _init_ssm(cfg, ks[1])
@@ -196,10 +215,16 @@ def _init_layer(cfg, key):
     if cfg.family == "moe":
         p["ln2"], _ = _norm_param(cfg)
         p["moe"], _ = _init_moe(cfg, ks[2])
-    elif cfg.family in ("dense", "vlm", "audio", "hybrid"):
+    elif cfg.has_attention:
         p["ln2"], _ = _norm_param(cfg)
         p["mlp"], _ = _init_mlp(cfg, ks[2])
     return p, layer_specs(cfg)
+
+
+def _init_mamba_layer(cfg, key):
+    k_ssm, k_mlp = jax.random.split(key)
+    return dict(ln1=_norm_param(cfg)[0], ssm=_init_ssm(cfg, k_ssm)[0],
+                ln2=_norm_param(cfg)[0], mlp=_init_mlp(cfg, k_mlp)[0])
 
 
 def param_specs(cfg) -> dict:
@@ -207,10 +232,13 @@ def param_specs(cfg) -> dict:
     _is_leaf = lambda a: isinstance(a, tuple) and all(
         x is None or isinstance(x, str) for x in a
     )
-    specs: dict = {"embed": ("vocab", "embed")}
-    specs["layers"] = jax.tree_util.tree_map(
-        lambda ax: ("layers",) + ax, layer_specs(cfg), is_leaf=_is_leaf
+    stacked = lambda tree: jax.tree_util.tree_map(
+        lambda ax: ("layers",) + ax, tree, is_leaf=_is_leaf
     )
+    specs: dict = {"embed": ("vocab", "embed")}
+    specs["layers"] = stacked(layer_specs(cfg))
+    if cfg.family == "interleaved":
+        specs[MAMBA_STACK] = stacked(mamba_layer_specs(cfg))
     specs["final_ln"] = _norm_specs(cfg)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ("embed", "vocab")
@@ -227,8 +255,13 @@ def init_params(cfg, key) -> tuple[dict, dict]:
     params["embed"] = jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model)) * 0.02
 
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    stacked = jax.vmap(lambda k: _init_layer(cfg, k)[0])(layer_keys)
-    params["layers"] = stacked
+    if cfg.family == "interleaved":
+        n_attn = cfg.num_attn_layers
+        params["layers"] = jax.vmap(lambda k: _init_layer(cfg, k)[0])(layer_keys[:n_attn])
+        params[MAMBA_STACK] = jax.vmap(lambda k: _init_mamba_layer(cfg, k))(
+            layer_keys[n_attn:])
+    else:
+        params["layers"] = jax.vmap(lambda k: _init_layer(cfg, k)[0])(layer_keys)
 
     params["final_ln"], _ = _norm_param(cfg)
     if not cfg.tie_embeddings:
@@ -332,6 +365,81 @@ def _block(
     return x, (new_cache or None), aux
 
 
+def _mamba_block(
+    lp: dict,
+    x: Array,
+    cfg,
+    ctx: FaultContext,
+    *,
+    state: Optional[dict] = None,
+    build_state: bool = False,
+    valid: Optional[Array] = None,
+    write_mask: Optional[Array] = None,
+):
+    """One Mamba layer of an interleaved stack: the mixer and an MLP, each
+    behind its own pre-norm. ``state`` (``conv``, ``h``) is continued from
+    (``models/ssm.py::ssm_block``, as is ``valid``); a slot whose
+    ``write_mask`` is False keeps its state. Returns (x, new state dict or
+    None, aux)."""
+    h = apply_norm(x, lp["ln1"], cfg.norm_eps)
+    cache = None if state is None else SSMCache(state["conv"], state["h"])
+    y, sc = ssm_block(lp["ssm"], h, cfg, ctx, cache=cache, build_cache=build_state,
+                      valid=valid)
+    x = x + y
+    h2 = apply_norm(x, lp["ln2"], cfg.norm_eps)
+    x = x + mlp_block(lp["mlp"], h2, cfg, ctx)
+    new = None if sc is None else dict(conv=sc.conv, h=sc.h)
+    if new is not None and write_mask is not None:
+        keep = lambda n, o: jnp.where(write_mask.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+        new = jax.tree_util.tree_map(keep, new, state)
+    return x, new, jnp.zeros((), jnp.float32)
+
+
+def layer_runs(cfg) -> list[tuple[str, int, int]]:
+    """The layer stack in order, as runs of consecutive layers of one kind:
+    (params key of the kind's stack, first layer in that stack, count).
+    Every family but the interleaved one is one run of ``layers``."""
+    if cfg.family != "interleaved":
+        return [("layers", 0, cfg.num_layers)]
+    runs: list = []
+    seen = {"layers": 0, MAMBA_STACK: 0}
+    for i in range(cfg.num_layers):
+        key = "layers" if cfg.is_attention_layer(i) else MAMBA_STACK
+        if runs and runs[-1][0] == key:
+            runs[-1][2] += 1
+        else:
+            runs.append([key, seen[key], 1])
+        seen[key] += 1
+    return [tuple(r) for r in runs]
+
+
+def _run_stack(cfg, params, x, layer, caches=None, wrap=None):
+    """Run the layer stack over hidden state ``x``: one ``lax.scan`` per run
+    of :func:`layer_runs`. ``layer(mamba, lp, h, lc)`` returns (h, the
+    layer's new cache, aux); ``caches`` maps each stack's params key to its
+    per-layer caches, stacked like the stack; ``wrap`` (e.g. remat) wraps
+    each scan body. Returns (x, summed aux, {stack key: the new caches,
+    stacked like the stack})."""
+    carry = (x, jnp.zeros((), jnp.float32))
+    outs: dict = {}
+    for key, start, n in layer_runs(cfg):
+
+        def body(carry, xs, mamba=key == MAMBA_STACK):
+            h, aux = carry
+            lp, lc = xs
+            h, nc, a = layer(mamba, lp, h, lc)
+            return (h, aux + a), nc
+
+        xs = (params[key], None if caches is None else caches[key])
+        if n != jax.tree_util.tree_leaves(params[key])[0].shape[0]:
+            xs = jax.tree_util.tree_map(lambda a: a[start : start + n], xs)
+        carry, out = jax.lax.scan(wrap(body) if wrap else body, carry, xs)
+        outs.setdefault(key, []).append(out)
+    outs = {k: v[0] if len(v) == 1 else jax.tree_util.tree_map(
+        lambda *a: jnp.concatenate(a), *v) for k, v in outs.items()}
+    return carry[0], carry[1], outs
+
+
 # ---------------------------------------------------------------------------
 # Embedding / unembedding
 # ---------------------------------------------------------------------------
@@ -405,21 +513,23 @@ def forward(
         ctx = healthy()
     x, positions = embed_inputs(cfg, params, batch, ctx)
 
-    def body(carry, lp):
-        h, aux = carry
-        h, _, a = _block(
-            lp, h, cfg, ctx,
-            positions=positions, attn_impl=attn_impl, moe_impl=moe_impl,
-            moe_cf=moe_cf,
-        )
-        h = shard_activation(h, ("batch", "seq_carry", "embed"))
-        return (h, aux + a), None
+    def layer(mamba, lp, h, _):
+        if mamba:
+            h, nc, a = _mamba_block(lp, h, cfg, ctx)
+        else:
+            h, nc, a = _block(
+                lp, h, cfg, ctx,
+                positions=positions, attn_impl=attn_impl, moe_impl=moe_impl,
+                moe_cf=moe_cf,
+            )
+        return shard_activation(h, ("batch", "seq_carry", "embed")), nc, a
 
+    wrap = None
     if remat != "none":
         policy = getattr(jax.checkpoint_policies, _REMAT_POLICIES[remat])
-        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+        wrap = lambda body: jax.checkpoint(body, policy=policy, prevent_cse=False)
 
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    x, aux, _ = _run_stack(cfg, params, x, layer, wrap=wrap)
     x = apply_norm(x, params["final_ln"], cfg.norm_eps)
     # tied unembed keeps its use-site mask (the lookup needs unmasked rows)
     logits = unembed(cfg, params, x, ctx_unembed if cfg.tie_embeddings else ctx)
@@ -483,17 +593,50 @@ def init_cache(cfg, batch: int, seq_len: int) -> dict:
 
     Layout: stacked [L, ...] arrays + scalar 'index'."""
     dtype = jnp.dtype(cfg.dtype)
-    L = cfg.num_layers
     c: dict = {"index": jnp.zeros((), jnp.int32)}
     if cfg.has_attention:
-        hkv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+        L, hkv, hd = cfg.num_attn_layers, cfg.num_kv_heads, cfg.resolved_head_dim
         s_buf = cache_buffer_len(cfg, seq_len)
         c["k"] = jnp.zeros((L, batch, hkv, s_buf, hd), dtype)
         c["v"] = jnp.zeros((L, batch, hkv, s_buf, hd), dtype)
     if cfg.has_ssm:
-        c["conv"] = jnp.zeros((L, batch, cfg.ssm_conv - 1, cfg.d_inner), dtype)
-        c["h"] = jnp.zeros((L, batch, cfg.d_inner, cfg.ssm_state), jnp.float32)
+        c.update(_ssm_state(cfg, batch))
     return c
+
+
+def _ssm_state(cfg, batch: int) -> dict:
+    """Zero recurrent state of every SSM layer for ``batch`` sequences: the
+    conv-input tail and the SSM state, float32, stacked over the layers."""
+    L, di = cfg.num_ssm_layers, cfg.d_inner
+    return dict(conv=jnp.zeros((L, batch, cfg.ssm_conv - 1, di), jnp.float32),
+                h=jnp.zeros((L, batch, di, cfg.ssm_state), jnp.float32))
+
+
+SSM_KEYS = ("conv", "h")
+
+
+def _stack_caches(cfg, cache: dict) -> dict:
+    """A cache dict's per-layer entries as :func:`_run_stack` takes them:
+    an interleaved stack's SSM state goes to its Mamba layers, the rest to
+    ``layers``."""
+    if cfg.family != "interleaved":
+        return {"layers": cache}
+    return {"layers": {k: v for k, v in cache.items() if k not in SSM_KEYS},
+            MAMBA_STACK: {k: cache[k] for k in SSM_KEYS}}
+
+
+def check_pageable(cfg, what: str) -> None:
+    """Refuse a model the paged serving path cannot run: it serves the
+    attention families and interleaved stacks, whose SSM state sits per
+    slot beside the page chain; an SSM-only or parallel-branch hybrid
+    model has no such path yet, and an encoder no decode."""
+    if cfg.family in ("ssm", "hybrid"):
+        raise ValueError(
+            f"{what} supports attention families and interleaved stacks only, "
+            f"not {cfg.family!r}"
+        )
+    if cfg.is_encoder:
+        raise ValueError(f"encoder-only arch has no decode path ({what})")
 
 
 def cache_specs(cfg) -> dict:
@@ -551,33 +694,45 @@ def prefill(
 
     ``segments`` (``(B, S)`` int, with per-segment restarting
     ``batch["positions"]``) packs several prompts into one row; attention
-    is masked to same-segment tokens (``repro.models.layers``).
+    is masked to same-segment tokens (``repro.models.layers``). An
+    interleaved stack's Mamba layers run one scan along the row, so a row
+    holds ONE prompt and its pad tail (segment 0), whose steps leave the
+    state untouched; the continuous engine never packs two.
     """
     ctx = ctx or healthy()
     x, positions = embed_inputs(cfg, params, batch, ctx)
     b, s = x.shape[0], x.shape[1]
     cache_len = cache_len or s
     s_buf = cache_buffer_len(cfg, cache_len)
-    if (full_kv or segments is not None or valid_len is not None) and (
-        cfg.has_ssm or cfg.is_encoder
-    ):
-        # SSM state is a running scan — right-pad tokens would advance it —
-        # and encoders attend bidirectionally, so pad keys aren't causal-masked
+    padded = full_kv or segments is not None or valid_len is not None
+    if padded and (cfg.family in ("ssm", "hybrid") or cfg.is_encoder):
+        # an SSM-only or parallel-branch stack has no pad-aware path, and
+        # encoders attend bidirectionally, so pad keys aren't causal-masked
         raise ValueError("padded/packed prefill supports causal attention families only")
+    valid = None  # the real tokens, for the Mamba layers' scan
+    if segments is not None:
+        valid = segments > 0
+    elif valid_len is not None:
+        vl = jnp.reshape(jnp.asarray(valid_len, jnp.int32), (-1, 1))
+        valid = jnp.broadcast_to(jnp.arange(s)[None] < vl, (b, s))
 
-    def body(carry, lp):
-        h, aux = carry
-        h, piece, a = _block(
-            lp, h, cfg, ctx,
-            positions=positions, attn_impl=attn_impl, moe_impl=moe_impl,
-            moe_cf=moe_cf, build_cache=True, segments=segments,
-        )
-        h = shard_activation(h, ("batch", "seq_carry", "embed"))
-        return (h, aux + a), piece
+    def layer(mamba, lp, h, _):
+        if mamba:
+            h, piece, a = _mamba_block(lp, h, cfg, ctx, build_state=True, valid=valid)
+        else:
+            h, piece, a = _block(
+                lp, h, cfg, ctx,
+                positions=positions, attn_impl=attn_impl, moe_impl=moe_impl,
+                moe_cf=moe_cf, build_cache=True, segments=segments,
+            )
+        return shard_activation(h, ("batch", "seq_carry", "embed")), piece, a
 
-    (x, _aux), pieces = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
+    x, _aux, stacks = _run_stack(cfg, params, x, layer)
+    pieces = stacks["layers"]
+    if cfg.family == "interleaved":
+        state = stacks[MAMBA_STACK]
+    elif cfg.has_ssm:
+        state = dict(conv=pieces["ssm"].conv, h=pieces["ssm"].h)
     x = apply_norm(x, params["final_ln"], cfg.norm_eps)
     if return_hidden:
         out = x
@@ -595,7 +750,8 @@ def prefill(
         k_new, v_new = pieces["kv"]
         dt = jnp.dtype(cfg.dtype)
         index = jnp.asarray(s if valid_len is None else valid_len, jnp.int32)
-        return out, dict(k=k_new.astype(dt), v=v_new.astype(dt), index=index)
+        kv = dict(k=k_new.astype(dt), v=v_new.astype(dt), index=index)
+        return out, (kv if not cfg.has_ssm else {**kv, **state})
 
     cache = init_cache(cfg, b, cache_len)
     if cfg.has_attention:
@@ -627,9 +783,7 @@ def prefill(
                 cache["v"], v_new.astype(cache["v"].dtype), 0, axis=3
             )
     if cfg.has_ssm:
-        sc = pieces["ssm"]
-        cache["conv"] = sc.conv.astype(cache["conv"].dtype)
-        cache["h"] = sc.h
+        cache.update(state)
     cache["index"] = jnp.asarray(s if valid_len is None else valid_len, jnp.int32)
     return out, cache
 
@@ -645,9 +799,10 @@ def prefill_chunk(
     row: Array,  # (max_pages_per_seq,) int32 — this slot's page chain
     prefix_len,  # traced scalar: tokens already prefilled (multiple of C)
     valid_len,  # traced scalar: real tokens in this chunk (== C except last)
+    ssm_state: Optional[dict] = None,  # interleaved: (conv, h) after the prefix
     moe_impl: str = "einsum",
     moe_cf: float = 1.25,
-) -> tuple[Array, Array, Array]:
+) -> tuple[Array, Array, Array, Optional[dict]]:
     """One chunked-prefill step: continue a prompt against its paged prefix.
 
     Gathers the slot's page chain into a dense buffer, runs the chunk as a
@@ -655,14 +810,17 @@ def prefill_chunk(
     over ``prefix + chunk`` valid keys — sliding windows are handled by the
     dense window mask, never the ring buffer, so chunk boundaries crossing
     the window are exact), and returns
-    ``(logits (1, V) at valid_len - 1, k_chunk, v_chunk (L, 1, Hkv, C, hd))``
-    for the caller to scatter into the pool. ONE compiled shape covers every
+    ``(logits (1, V) at valid_len - 1, k_chunk, v_chunk (L, 1, Hkv, C, hd),
+    ssm_state)`` for the caller to scatter into the pool. An interleaved
+    stack's Mamba layers continue from ``ssm_state`` (``conv``
+    ``(Ls, 1, K-1, d_inner)``, ``h`` ``(Ls, 1, d_inner, N)``) and return the
+    state after the chunk's last real token (the pad tail leaves it as it
+    was); other models return None there. ONE compiled shape covers every
     chunk of every prompt: prefix/valid are traced, the chain width is the
     engine-wide ``max_pages_per_seq``.
     """
     ctx = ctx or healthy()
-    if cfg.has_ssm or cfg.is_encoder:
-        raise ValueError("chunked prefill supports causal attention families only")
+    check_pageable(cfg, "chunked prefill")
     b, s = tokens.shape
     if b != 1:
         raise ValueError(f"chunked prefill is one request per dispatch, got batch {b}")
@@ -685,27 +843,27 @@ def prefill_chunk(
         g = g.reshape(L, hkv, cap, hd)
         return jnp.pad(g, ((0, 0), (0, 0), (0, w_buf - cap), (0, 0)))[:, None]
 
-    layer_cache = {"k": chain_dense(k_pages), "v": chain_dense(v_pages)}
+    caches = {"layers": {"k": chain_dense(k_pages), "v": chain_dense(v_pages)}}
+    if cfg.family == "interleaved":
+        caches[MAMBA_STACK] = ssm_state
+    valid = jnp.arange(s)[None] < vl
 
-    def body(carry, xs):
-        h, aux = carry
-        lp, lc = xs
-        h, nc, a = _block(
+    def layer(mamba, lp, h, lc):
+        if mamba:
+            return _mamba_block(lp, h, cfg, ctx, state=lc, valid=valid)
+        return _block(
             lp, h, cfg, ctx,
             positions=positions, attn_impl="dense", moe_impl=moe_impl,
             moe_cf=moe_cf, cache=lc, cache_len=prefix,
         )
-        return (h, aux + a), nc
 
-    (x, _aux), new_layer_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params["layers"], layer_cache)
-    )
+    x, _aux, new = _run_stack(cfg, params, x, layer, caches)
     x = apply_norm(x, params["final_ln"], cfg.norm_eps)
     last = jax.lax.dynamic_slice_in_dim(x, vl - 1, 1, axis=1)
     logits = unembed(cfg, params, last, ctx)[:, 0]
-    k_chunk = jax.lax.dynamic_slice_in_dim(new_layer_cache["k"], prefix, s, axis=3)
-    v_chunk = jax.lax.dynamic_slice_in_dim(new_layer_cache["v"], prefix, s, axis=3)
-    return logits, k_chunk, v_chunk
+    k_chunk = jax.lax.dynamic_slice_in_dim(new["layers"]["k"], prefix, s, axis=3)
+    v_chunk = jax.lax.dynamic_slice_in_dim(new["layers"]["v"], prefix, s, axis=3)
+    return logits, k_chunk, v_chunk, new.get(MAMBA_STACK)
 
 
 def decode_step(
@@ -745,22 +903,19 @@ def decode_step(
 
     layer_cache = {k: v for k, v in cache.items() if k != "index"}
 
-    def body(carry, xs):
-        h, aux = carry
-        lp, lc = xs
-        h, nc, a = _block(
+    def layer(mamba, lp, h, lc):
+        if mamba:
+            return _mamba_block(lp, h, cfg, ctx, state=lc)
+        return _block(
             lp, h, cfg, ctx,
             positions=positions, attn_impl="dense", moe_impl=moe_impl,
             moe_cf=moe_cf, cache=lc, cache_len=index,
         )
-        return (h, aux + a), nc
 
-    (x, _aux), new_layer_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params["layers"], layer_cache)
-    )
+    x, _aux, new = _run_stack(cfg, params, x, layer, _stack_caches(cfg, layer_cache))
     x = apply_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed(cfg, params, x, ctx)
-    new_cache = dict(new_layer_cache)
+    new_cache = {k: v for stack in new.values() for k, v in stack.items()}
     new_cache["index"] = index + s
     return logits, new_cache
 
@@ -774,24 +929,22 @@ def init_paged_cache(
     pools (page 0 reserved as the scratch page — see
     ``repro.serve.kvcache.PageAllocator``), ``block_tables`` is
     ``(num_slots, max_pages_per_seq)`` int32 page ids and ``seq_lens`` is the
-    per-slot cached-token count. Attention-family models only: SSM/hybrid
-    state is O(1) per slot and needs no paging, and encoders have no decode.
+    per-slot cached-token count. The pool covers the attention layers; an
+    interleaved stack's Mamba layers keep their state per slot beside it
+    (``conv``, ``h``: O(1) in sequence length, so nothing to page).
     """
-    if cfg.has_ssm:
-        raise ValueError(
-            f"paged KV cache supports attention families only; {cfg.family!r} "
-            "carries SSM state (which is O(1) per slot and needs no paging)"
-        )
-    if cfg.is_encoder:
-        raise ValueError("encoder-only arch has no decode path to page")
+    check_pageable(cfg, "paged KV cache")
     dtype = jnp.dtype(cfg.dtype)
-    L, hkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
-    return {
+    L, hkv, hd = cfg.num_attn_layers, cfg.num_kv_heads, cfg.resolved_head_dim
+    cache = {
         "k_pages": jnp.zeros((L, num_pages, hkv, page_size, hd), dtype),
         "v_pages": jnp.zeros((L, num_pages, hkv, page_size, hd), dtype),
         "block_tables": jnp.zeros((num_slots, max_pages_per_seq), jnp.int32),
         "seq_lens": jnp.zeros((num_slots,), jnp.int32),
     }
+    if cfg.has_ssm:
+        cache.update(_ssm_state(cfg, num_slots))
+    return cache
 
 
 def _decode_step_paged(
@@ -805,9 +958,10 @@ def _decode_step_paged(
     moe_cf: float = 1.25,
     active: Optional[Array] = None,
 ) -> tuple[Array, dict]:
-    """Gather-based paged decode: per-slot positions, shared page pool."""
-    if cfg.has_ssm:
-        raise ValueError(f"paged decode supports attention families only, not {cfg.family!r}")
+    """Gather-based paged decode: per-slot positions, shared page pool; an
+    interleaved stack's Mamba layers step each slot's own state (a slot
+    that is not ``active`` keeps it)."""
+    check_pageable(cfg, "paged decode")
     b, s = tokens.shape
     lens = cache["seq_lens"]
     bt = cache["block_tables"]
@@ -815,28 +969,28 @@ def _decode_step_paged(
     x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
     x = shard_activation(x, ("batch", "seq", "embed"))
 
-    def body(carry, xs):
-        h, aux = carry
-        lp, (kp, vp) = xs
+    def layer(mamba, lp, h, lc):
+        if mamba:
+            return _mamba_block(lp, h, cfg, ctx, state=lc, write_mask=active)
+        kp, vp = lc
         view = PagedKVView(kp, vp, bt, lens, active)
-        h, nc, a = _block(
+        return _block(
             lp, h, cfg, ctx,
             positions=positions, attn_impl="dense", moe_impl=moe_impl,
             moe_cf=moe_cf, cache=view,
         )
-        return (h, aux + a), nc
 
-    (x, _aux), new_pages = jax.lax.scan(
-        body,
-        (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], (cache["k_pages"], cache["v_pages"])),
-    )
+    caches = {"layers": (cache["k_pages"], cache["v_pages"])}
+    if cfg.family == "interleaved":
+        caches[MAMBA_STACK] = {k: cache[k] for k in SSM_KEYS}
+    x, _aux, new = _run_stack(cfg, params, x, layer, caches)
     x = apply_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed(cfg, params, x, ctx)
     advanced = lens + s if active is None else jnp.where(active, lens + s, lens)
     return logits, dict(
-        k_pages=new_pages["kp"],
-        v_pages=new_pages["vp"],
+        k_pages=new["layers"]["kp"],
+        v_pages=new["layers"]["vp"],
         block_tables=bt,
         seq_lens=advanced,
+        **new.get(MAMBA_STACK, {}),
     )

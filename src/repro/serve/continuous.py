@@ -38,6 +38,14 @@ the jitted steps; the host loop only moves tiny per-slot flags (emitted
 tokens, the active mask) to run admission/retirement between dispatches,
 plus the int32 pack/chunk index maps built by ``repro.serve.bucketing``.
 
+An interleaved stack (Jamba: Mamba layers between attention layers) keeps
+each slot's recurrent state — every Mamba layer's conv-input tail and SSM
+state — beside its page chain, in the paged cache; the pool covers the
+attention layers only. A prompt's first prefill dispatch starts the state
+from zero, and each chunk continues from the one before it. Its prompts
+are never packed: one scan runs along a packed row, so a second prompt
+would start from the first one's state.
+
 A serving chip's weights are frozen, so on a faulty chip the fault mask is
 applied once, not per use: at build (and on :meth:`set_silicon`) one small
 jitted program masks every array-mapped GEMM weight, and a tied model's
@@ -60,6 +68,7 @@ from repro.core.mapping import masked_weight
 from repro.core.masking import MASK_SCOPE, FaultContext, healthy, is_array_mapped
 from repro.launch.hlo_cost import module_name, scoped_instructions
 from repro.models import model as M
+from repro.models.ssm import MAMBA_SCOPE, SCAN_SCOPE
 from repro.obs.alerts import AlertEngine, AlertRule
 from repro.obs.health import HealthConfig, HealthTracker
 from repro.obs.hooks import PoolMonitor, RequestTracer
@@ -79,6 +88,7 @@ from repro.serve.kvcache import (
     PageAllocator,
     page_bytes,
     pages_needed,
+    ssm_state_bytes,
 )
 
 __all__ = [
@@ -310,7 +320,8 @@ class ContinuousBatchingEngine:
 
     ``prefill_buckets=None`` disables the planner (one exact-length
     admission program per distinct prompt length — the unbucketed baseline
-    ``benchmarks/serve_bench.py --heavy-traffic`` measures against).
+    ``benchmarks/serve_bench.py --heavy-traffic`` measures against). A
+    model with SSM layers packs one prompt per admission (``max_pack`` 1).
     """
 
     def __init__(
@@ -332,13 +343,7 @@ class ContinuousBatchingEngine:
         health_config: Optional[HealthConfig] = None,
         alert_rules: Optional[Sequence[AlertRule]] = None,
     ):
-        if cfg.has_ssm:
-            raise ValueError(
-                f"continuous batching supports attention families only; "
-                f"{cfg.family!r} carries unpaged SSM state"
-            )
-        if cfg.is_encoder:
-            raise ValueError("encoder-only arch has no decode path")
+        M.check_pageable(cfg, "continuous batching")
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.cfg = cfg
@@ -371,7 +376,8 @@ class ContinuousBatchingEngine:
                 )
             if max_pack < 1:
                 raise ValueError(f"max_pack must be >= 1, got {max_pack}")
-            self.max_pack = int(max_pack)
+            # one scan runs along a packed row: SSM state would carry over
+            self.max_pack = 1 if cfg.has_ssm else int(max_pack)
         # every loop-carried operand (cur logits, paged cache, key, active
         # mask, remaining budgets) is re-bound from the previous dispatch's
         # outputs — donate them all so the page pool never round-trips
@@ -402,6 +408,12 @@ class ContinuousBatchingEngine:
         # (core/masking.py), from the AOT programs: empty lists, the mask
         # being applied at load; see warmup()
         self.mask_ops: dict[str, list[str]] = {}
+        # ... and to its ops under the Mamba mixers' and scans' scopes
+        # (models/ssm.py): both empty for a model without SSM layers
+        self.mamba_ops: dict[str, list[str]] = {}
+        self.scan_ops: dict[str, list[str]] = {}
+        if cfg.has_ssm:
+            self.obs.count("ssm.state_bytes", ssm_state_bytes(cfg, num_slots))
         # fault detection (ROADMAP item 2): an ABFT prober dispatched every
         # probe_every decode dispatches, feeding the health state machine
         # and the alert engine. Probes are SEPARATE dispatches through a
@@ -527,11 +539,15 @@ class ContinuousBatchingEngine:
         h = hidden[0, gather_pos]  # (max_pack, d) — one last-token row per segment
         logits = M.unembed(self.cfg, params, h[None], ctx)[0]  # (max_pack, V)
         cache = dict(
+            cache,
             k_pages=kp,
             v_pages=vp,
             block_tables=cache["block_tables"].at[slots].set(rows),
             seq_lens=cache["seq_lens"].at[slots].set(seq_lens),
         )
+        for k in M.SSM_KEYS if self.cfg.has_ssm else ():
+            # the row's one prompt: its state, from zero, replaces the slot's
+            cache[k] = cache[k].at[:, slots].set(dense[k])
         cur = cur.at[slots].set(logits.astype(cur.dtype))
         active = active.at[slots].set(True)
         remaining = remaining.at[slots].set(budgets)
@@ -545,16 +561,24 @@ class ContinuousBatchingEngine:
         prefix (``models/model.py::prefill_chunk``), scatter the chunk's KV
         into the chain, and — on the final chunk (``activate``) — seed the
         slot's logits/budget and flip it live. Prefix/valid are traced, so
-        every chunk of every prompt shares one compiled program."""
-        logits, kc, vc = M.prefill_chunk(
+        every chunk of every prompt shares one compiled program. A model
+        with SSM layers continues the slot's state, zero at the prompt's
+        first chunk, and keeps the state after the chunk in the slot."""
+        state = None
+        if self.cfg.has_ssm:
+            state = {k: jnp.where(prefix > 0, cache[k][:, slot], 0)[:, None]
+                     for k in M.SSM_KEYS}
+        logits, kc, vc, state = M.prefill_chunk(
             params, tokens, self.cfg, ctx,
             k_pages=cache["k_pages"], v_pages=cache["v_pages"], row=row,
-            prefix_len=prefix, valid_len=valid,
+            prefix_len=prefix, valid_len=valid, ssm_state=state,
         )
         k = jnp.transpose(kc[:, 0], (2, 0, 1, 3))
         v = jnp.transpose(vc[:, 0], (2, 0, 1, 3))
         new_len = jnp.where(activate, prefix + valid, cache["seq_lens"][slot])
         cache = dict(
+            cache,
+            **{k: cache[k].at[:, slot].set(v[:, 0]) for k, v in (state or {}).items()},
             k_pages=cache["k_pages"].at[:, page_ix, :, page_off].set(k.astype(cache["k_pages"].dtype)),
             v_pages=cache["v_pages"].at[:, page_ix, :, page_off].set(v.astype(cache["v_pages"].dtype)),
             block_tables=cache["block_tables"].at[slot].set(row),
@@ -569,16 +593,11 @@ class ContinuousBatchingEngine:
 
     def _state_structs(self):
         cfg = self.cfg
-        dtype = jnp.dtype(cfg.dtype)
-        L, hkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
-        pool = jax.ShapeDtypeStruct((L, self.num_pages, hkv, self.page_size, hd), dtype)
+        cache = jax.eval_shape(
+            lambda: M.init_paged_cache(cfg, self.num_pages, self.page_size,
+                                       self.num_slots, self.max_pages_per_seq))
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        cache = dict(
-            k_pages=pool, v_pages=pool,
-            block_tables=i32(self.num_slots, self.max_pages_per_seq),
-            seq_lens=i32(self.num_slots),
-        )
-        cur = jax.ShapeDtypeStruct((self.num_slots, cfg.vocab_size), dtype)
+        cur = jax.ShapeDtypeStruct((self.num_slots, cfg.vocab_size), jnp.dtype(cfg.dtype))
         active = jax.ShapeDtypeStruct((self.num_slots,), jnp.bool_)
         remaining = i32(self.num_slots)
         return cache, cur, active, remaining
@@ -594,16 +613,21 @@ class ContinuousBatchingEngine:
         publishes so that a device trace's mask time can be told apart
         (programs that share a module name, the bucket ladder's, share one
         list): none, on a healthy chip and on a premasked faulty one alike.
-        Returns the AOT program count."""
+        The same maps of the ops under the Mamba mixers' and scans' scopes
+        (``mamba_ops``, ``scan_ops``) are published beside it. Returns the
+        AOT program count."""
         if self.prefill_buckets is None:
             raise ValueError("warmup() needs bucketed prefill; prefill_buckets is None")
         self._compile_programs()
-        mask_ops: dict = {}
+        maps: dict = {MASK_SCOPE: {}, MAMBA_SCOPE: {}, SCAN_SCOPE: {}}
         for exe in self._aot.values():
             hlo = exe.as_text()
-            mask_ops.setdefault(module_name(hlo), set()).update(
-                scoped_instructions(hlo, MASK_SCOPE))
-        self.mask_ops = {m: sorted(ops) for m, ops in mask_ops.items()}
+            for scope, ops in maps.items():
+                ops.setdefault(module_name(hlo), set()).update(
+                    scoped_instructions(hlo, scope))
+        self.mask_ops, self.mamba_ops, self.scan_ops = (
+            {m: sorted(ops) for m, ops in maps[scope].items()}
+            for scope in (MASK_SCOPE, MAMBA_SCOPE, SCAN_SCOPE))
         return len(self._aot)
 
     def _compile_programs(self) -> None:
@@ -684,7 +708,8 @@ class ContinuousBatchingEngine:
                         annotate=jax.profiler.TraceAnnotation)
         if rec:
             rec.instant("serve.programs", proc="serve", track="engine",
-                        args=dict(fault_mask=self.mask_ops))
+                        args={MASK_SCOPE: self.mask_ops, MAMBA_SCOPE: self.mamba_ops,
+                              SCAN_SCOPE: self.scan_ops})
 
         V = self.cfg.vocab_size
         dtype = jnp.dtype(self.cfg.dtype)
@@ -809,6 +834,8 @@ class ContinuousBatchingEngine:
                         slot, r, pages = adm
                         table.outputs_admitted[r.rid] = clock
                         stats.admitted += 1
+                        if self.cfg.has_ssm:
+                            rec.count("ssm.state_reset")
                         plen = len(r.tokens)
                         if top is not None and plen > top:
                             flush_pack()

@@ -38,6 +38,7 @@ __all__ = [
     "chain_layout",
     "dense_kv_bytes",
     "page_bytes",
+    "ssm_state_bytes",
 ]
 
 DEFAULT_PAGE_SIZE = 8
@@ -157,13 +158,21 @@ def chain_layout(k_dense: jax.Array, page_size: int, chain_len: int) -> jax.Arra
 
 
 def _kv_entry_bytes(cfg) -> int:
-    """Bytes of one token's K+V across all layers."""
-    return 2 * cfg.num_layers * cfg.num_kv_heads * cfg.resolved_head_dim * jnp.dtype(cfg.dtype).itemsize
+    """Bytes of one token's K+V across the attention layers."""
+    return 2 * cfg.num_attn_layers * cfg.num_kv_heads * cfg.resolved_head_dim * jnp.dtype(cfg.dtype).itemsize
 
 
 def page_bytes(cfg, page_size: int) -> int:
-    """Resident bytes of ONE page (K+V, all layers)."""
+    """Resident bytes of ONE page (K+V, all attention layers)."""
     return _kv_entry_bytes(cfg) * int(page_size)
+
+
+def ssm_state_bytes(cfg, num_slots: int) -> int:
+    """Resident bytes of the recurrent state ``num_slots`` slots hold beside
+    their page chains: each SSM layer's float32 conv-input tail and SSM
+    state (``models/model.py::init_paged_cache``)."""
+    per_layer = cfg.d_inner * (cfg.ssm_conv - 1 + cfg.ssm_state) * 4
+    return cfg.num_ssm_layers * per_layer * int(num_slots)
 
 
 def dense_kv_bytes(cfg, batch: int, cache_len: int) -> int:

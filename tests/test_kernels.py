@@ -125,6 +125,38 @@ def test_selective_scan_sweep(b, l, d, n, bd, bl):
     np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), rtol=2e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("split", [0, 16, 23, 40])
+def test_selective_scan_continues_from_h0(split):
+    """The kernel started from ``h0`` (interpret mode) matches the reference
+    started from it, and a scan split at ``split`` — the second part
+    continued from the first part's final state — reproduces the unsplit
+    scan (0 and 40: one part empty of the other's steps)."""
+    from repro.kernels.mamba_scan.ops import selective_scan
+
+    b, l, d, n = 2, 40, 24, 8
+    ks = jax.random.split(KEY, 7)
+    u = jax.random.normal(ks[0], (b, l, d))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, d)))
+    a = -jnp.exp(jax.random.normal(ks[2], (d, n)))
+    bb = jax.random.normal(ks[3], (b, l, n))
+    c = jax.random.normal(ks[4], (b, l, n))
+    dd = jax.random.normal(ks[5], (d,))
+    h0 = jax.random.normal(ks[6], (b, d, n))
+    yr, hr = selective_scan_ref(u, dt, a, bb, c, dd, h0)
+    yk, hk = selective_scan_pallas(u, dt, a, bb, c, dd, h0, bd=8, bl=8, interpret=True)
+    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), rtol=2e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), rtol=2e-5, atol=1e-4)
+    ys, h = [], h0
+    for lo, hi in ((0, split), (split, l)):
+        if hi > lo:
+            y, h = selective_scan(u[:, lo:hi], dt[:, lo:hi], a, bb[:, lo:hi], c[:, lo:hi], dd, h,
+                                  bd=8, bl=16, interpret=True)
+            ys.append(y)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(ys, 1)), np.asarray(yr),
+                               rtol=2e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=2e-5, atol=1e-4)
+
+
 def test_selective_step_matches_scan():
     b, l, d, n = 2, 16, 8, 4
     ks = jax.random.split(KEY, 6)

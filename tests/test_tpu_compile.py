@@ -7,7 +7,7 @@ lowers one kernel launch through its ``ops.py`` wrapper with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology and
 checks that the compiled program holds the Pallas kernel
 (``tpu_custom_call``). Widths: smollm-135m's GEMMs and attention heads, and
-falcon-mamba-7b's scan.
+falcon-mamba-7b's and jamba2-3b's scans.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time."""
@@ -21,6 +21,7 @@ from repro.configs import get_arch
 
 SMOLLM = get_arch("smollm-135m")
 MAMBA = get_arch("falcon-mamba-7b")
+JAMBA = get_arch("jamba2-3b")
 D, F, HD = SMOLLM.d_model, SMOLLM.d_ff, SMOLLM.resolved_head_dim
 HQ, HKV = SMOLLM.num_heads, SMOLLM.num_kv_heads
 GEMMS = [(D, D), (D, HKV * HD), (D, F), (F, D), (D, SMOLLM.vocab_size)]
@@ -116,4 +117,19 @@ def test_selective_scan_compiles(spec, dtype):
         lambda *a: selective_scan(*a, interpret=False),
         seq, seq, spec((MAMBA.d_inner, MAMBA.ssm_state), jnp.float32), bc, bc,
         spec((MAMBA.d_inner,), jnp.float32),
+    )
+
+
+def test_selective_scan_from_a_state_compiles(spec):
+    """Jamba's chunked prefill: a 256-token chunk of bf16 inputs with float32
+    time steps, continued from a float32 state."""
+    from repro.kernels.mamba_scan.ops import selective_scan
+
+    di, n, chunk = JAMBA.d_inner, JAMBA.ssm_state, 256
+    bc = spec((1, chunk, n), jnp.bfloat16)
+    _assert_compiles_to_kernel(
+        lambda *a: selective_scan(*a, interpret=False),
+        spec((1, chunk, di), jnp.bfloat16), spec((1, chunk, di), jnp.float32),
+        spec((di, n), jnp.float32), bc, bc, spec((di,), jnp.float32),
+        spec((1, di, n), jnp.float32),
     )
