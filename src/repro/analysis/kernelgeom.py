@@ -37,7 +37,7 @@ from repro.kernels.common import (
     pad_to_multiple,
     vmem_footprint,
 )
-from repro.kernels.mamba_scan.mamba_scan import sublane_rows
+from repro.kernels.mamba_scan.mamba_scan import scan_blocks, scratch_shapes, sublane_rows
 from repro.kernels.masked_matmul.masked_matmul import _mask_axis_plan
 
 __all__ = [
@@ -337,17 +337,19 @@ def mamba_scan_launch(
     dim: int,
     state: int,
     *,
-    bd: int = 256,
-    bl: int = 128,
+    bd: Optional[int] = None,
+    bl: Optional[int] = None,
     dtype: Any = jnp.float32,
 ) -> KernelLaunch:
-    """Geometry of ``mamba_scan.ops.selective_scan`` (B, L, D) + state N."""
-    bd_ = min(bd, dim)
-    bl_ = pad_to_multiple(min(bl, length), sublane_rows(dtype))
-    dim_p = pad_to_multiple(dim, bd_)
-    len_p = pad_to_multiple(length, bl_)
+    """Geometry of ``mamba_scan.ops.selective_scan`` (B, L, D) + state N,
+    every input at ``dtype``; blocks not given come from the shape
+    (``scan_blocks``), as in the wrapper."""
+    bd_, bl_, dim_p, len_p = scan_blocks(length, dim, state, dtype, bd=bd, bl=bl)
     n = state
     seq, bc = (batch, len_p, dim_p), (batch, len_p, n)
+    scratch = tuple(
+        (shape, sdt, False) for shape, sdt in scratch_shapes(bd_, n, sublane_rows(dtype))
+    )
     return KernelLaunch(
         kernel="mamba_scan",
         dims=(batch, dim_p, len_p),
@@ -362,8 +364,10 @@ def mamba_scan_launch(
             ((1, bd_, n), jnp.float32, True, (batch, dim_p, n)),  # h0 in
             ((1, bl_, bd_), dtype, True, seq),  # y out
             ((1, bd_, n), jnp.float32, True, (batch, dim_p, n)),  # h_last out
-            ((bd_, n), jnp.float32, False),  # h scratch
-        ),
+            # a tile's B and C transposed to (N, tb), states on the sublanes
+            ((n, sublane_rows(dtype)), jnp.float32, False),
+            ((n, sublane_rows(dtype)), jnp.float32, False),
+        ) + scratch,  # h and A (N, bd); dA, dBu (tb, N, bd); the tile's y
     )
 
 

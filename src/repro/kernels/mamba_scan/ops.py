@@ -13,8 +13,10 @@ from: a prompt streamed in chunks carries each chunk's final state into
 the next, and splitting a scan anywhere reproduces the unsplit one."""
 from __future__ import annotations
 
-from repro.kernels.common import is_tpu_backend, pad_axes_to, pad_to_multiple, tuned_block
-from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas, sublane_rows
+import jax.numpy as jnp
+
+from repro.kernels.common import is_tpu_backend, pad_axes_to, tuned_block
+from repro.kernels.mamba_scan.mamba_scan import scan_blocks, selective_scan_pallas
 from repro.kernels.mamba_scan.ref import selective_scan_ref, selective_step_ref
 
 
@@ -23,25 +25,25 @@ def selective_scan(
     interpret=None,
 ):
     """``bd``/``bl`` default to the tuning cache's winner for this launch
-    when one exists, else the 256/128 heuristics (``tuned_block`` seam)."""
+    when one exists, else to blocks chosen from the shape
+    (``scan_blocks``; ``tuned_block`` seam)."""
     if interpret is None:
         if not is_tpu_backend():
             return selective_scan_ref(u, dt, a, b, c, d, h0)
         interpret = False
     bsz, length, dim = u.shape
+    n = a.shape[1]
+    narrow = min((u.dtype, dt.dtype, b.dtype, c.dtype), key=lambda t: jnp.dtype(t).itemsize)
+    heuristic = scan_blocks(length, dim, n, narrow)
     blocks = tuned_block(
         "mamba_scan",
-        dict(b=bsz, l=length, d=dim, n=a.shape[1]),
+        dict(b=bsz, l=length, d=dim, n=n),
         u.dtype,
         interpret=interpret,
-        defaults=dict(bd=256, bl=128),
+        defaults=dict(bd=heuristic[0], bl=heuristic[1]),
         overrides=dict(bd=bd, bl=bl),
     )
-    bd, bl = blocks["bd"], blocks["bl"]
-    bd_ = min(bd, dim)
-    bl_ = pad_to_multiple(min(bl, length), sublane_rows(u.dtype))
-    dim_p = pad_to_multiple(dim, bd_)
-    len_p = pad_to_multiple(length, bl_)
+    bd_, bl_, dim_p, len_p = scan_blocks(length, dim, n, narrow, **blocks)
     up = pad_axes_to(u, {1: len_p, 2: dim_p})
     dtp = pad_axes_to(dt, {1: len_p, 2: dim_p})
     ap = pad_axes_to(a, {0: dim_p})

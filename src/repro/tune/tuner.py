@@ -65,11 +65,12 @@ SHAPE_FIELDS = {
 }
 
 # today's ops.py heuristic defaults — the hillclimb seed and the fallback
+# (None: the wrapper chooses the block from the shape)
 HEURISTIC_BLOCKS = {
     "masked_matmul": dict(bm=512, bn=512, bk=512),
     "flash_attention": dict(bq=128, bkv=128),
     "decode_attention": dict(bkv=128),
-    "mamba_scan": dict(bd=256, bl=128),
+    "mamba_scan": dict(bd=None, bl=None),
 }
 
 
@@ -217,7 +218,7 @@ KERNELS: dict[str, KernelSpace] = {
     "mamba_scan": KernelSpace(
         params=("bd", "bl"),
         axes=dict(bd="d", bl="l"),
-        floors=dict(bd=8, bl=8),
+        floors=dict(bd=128, bl=8),  # channels on the lanes
         build_launch=_ms_launch,
         make_runner=_ms_runner,
         launch_slots=dict(bd=1, bl=2),

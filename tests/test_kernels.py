@@ -10,7 +10,6 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.masked_matmul.ops import masked_matmul
 from repro.kernels.masked_matmul.ref import masked_matmul_ref
-from repro.kernels.mamba_scan.mamba_scan import selective_scan_pallas
 from repro.kernels.mamba_scan.ref import selective_scan_ref, selective_step_ref
 
 KEY = jax.random.PRNGKey(0)
@@ -107,54 +106,94 @@ def test_flash_attention_sweep(b, hq, hkv, sq, skv, d, causal, window, off, dtyp
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "b,l,d,n,bd,bl",
-    [(2, 64, 32, 8, 16, 16), (1, 128, 64, 16, 64, 32), (3, 32, 16, 4, 16, 32)],
-)
-def test_selective_scan_sweep(b, l, d, n, bd, bl):
-    ks = jax.random.split(KEY, 6)
-    u = jax.random.normal(ks[0], (b, l, d))
+def _scan_inputs(b, l, d, n, dtype=jnp.float32, key=KEY):
+    """u, B and C at ``dtype``; dt, A, D and a state float32, as the model
+    passes them."""
+    ks = jax.random.split(key, 7)
+    u = jax.random.normal(ks[0], (b, l, d)).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, d)))
     a = -jnp.exp(jax.random.normal(ks[2], (d, n)))
-    bb = jax.random.normal(ks[3], (b, l, n))
-    c = jax.random.normal(ks[4], (b, l, n))
+    bb = jax.random.normal(ks[3], (b, l, n)).astype(dtype)
+    c = jax.random.normal(ks[4], (b, l, n)).astype(dtype)
     dd = jax.random.normal(ks[5], (d,))
+    h0 = jax.random.normal(ks[6], (b, d, n))
+    return u, dt, a, bb, c, dd, h0
+
+
+def _assert_scan_close(y, h, yr, hr):
+    """y at its own dtype's tolerance; the float32 state tightly."""
+    rtol = dtype_tol(y.dtype)[0]
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(yr, np.float32),
+                               rtol=rtol, atol=max(1e-4, rtol))
+    np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "b,l,d,n,bd,bl,dtype",
+    [
+        pytest.param(2, 64, 32, 8, 16, 16, jnp.float32, id="2-64-32-8-16-16"),
+        pytest.param(1, 128, 64, 16, 64, 32, jnp.float32, id="1-128-64-16-64-32"),
+        pytest.param(3, 32, 16, 4, 16, 32, jnp.float32, id="3-32-16-4-16-32"),
+        # channels on the lanes: 200 channels padded to two lane blocks and
+        # sliced back; bf16 u/B/C with float32 dt over two time blocks, the
+        # second half padded; the blocks chosen from the shape
+        pytest.param(1, 32, 200, 16, 128, 32, jnp.float32, id="lane-pad-sliced"),
+        pytest.param(2, 48, 256, 16, 128, 32, jnp.bfloat16, id="bf16-two-time-blocks"),
+        pytest.param(1, 64, 384, 16, None, None, jnp.bfloat16, id="blocks-from-shape"),
+    ],
+)
+def test_selective_scan_sweep(b, l, d, n, bd, bl, dtype):
+    from repro.kernels.mamba_scan.ops import selective_scan
+
+    u, dt, a, bb, c, dd, _ = _scan_inputs(b, l, d, n, dtype)
     yr, hr = selective_scan_ref(u, dt, a, bb, c, dd)
-    yk, hk = selective_scan_pallas(u, dt, a, bb, c, dd, bd=bd, bl=bl, interpret=True)
-    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), rtol=2e-5, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), rtol=2e-5, atol=1e-4)
+    yk, hk = selective_scan(u, dt, a, bb, c, dd, bd=bd, bl=bl, interpret=True)
+    assert yk.shape == yr.shape and yk.dtype == yr.dtype and hk.shape == hr.shape
+    _assert_scan_close(yk, hk, yr, hr)
 
 
-@pytest.mark.parametrize("split", [0, 16, 23, 40])
-def test_selective_scan_continues_from_h0(split):
+@pytest.mark.parametrize(
+    "split,b,l,d,n,bd,bl,dtype,pad",
+    [
+        pytest.param(0, 2, 40, 24, 8, 8, 8, jnp.float32, 0, id="0"),
+        pytest.param(16, 2, 40, 24, 8, 8, 8, jnp.float32, 0, id="16"),
+        pytest.param(23, 2, 40, 24, 8, 8, 8, jnp.float32, 0, id="23"),
+        pytest.param(40, 2, 40, 24, 8, 8, 8, jnp.float32, 0, id="40"),
+        # a bf16 chunk of two time blocks from a nonzero state, continued by
+        # the next chunk at the block boundary (Jamba's chunked prefill)
+        pytest.param(32, 2, 64, 256, 16, 128, 32, jnp.bfloat16, 0, id="bf16-two-blocks"),
+        # the last 16 steps right-padded (dt = u = 0): the final state is
+        # the one after the last real step; 200 channels lane-padded
+        pytest.param(24, 1, 64, 200, 16, 128, 32, jnp.float32, 16, id="right-padded"),
+    ],
+)
+def test_selective_scan_continues_from_h0(split, b, l, d, n, bd, bl, dtype, pad):
     """The kernel started from ``h0`` (interpret mode) matches the reference
     started from it, and a scan split at ``split`` — the second part
     continued from the first part's final state — reproduces the unsplit
-    scan (0 and 40: one part empty of the other's steps)."""
+    scan (0 and 40: one part empty of the other's steps). Right-padded
+    steps with ``dt = 0`` leave the final state as the last real step left
+    it."""
     from repro.kernels.mamba_scan.ops import selective_scan
 
-    b, l, d, n = 2, 40, 24, 8
-    ks = jax.random.split(KEY, 7)
-    u = jax.random.normal(ks[0], (b, l, d))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, d)))
-    a = -jnp.exp(jax.random.normal(ks[2], (d, n)))
-    bb = jax.random.normal(ks[3], (b, l, n))
-    c = jax.random.normal(ks[4], (b, l, n))
-    dd = jax.random.normal(ks[5], (d,))
-    h0 = jax.random.normal(ks[6], (b, d, n))
+    u, dt, a, bb, c, dd, h0 = _scan_inputs(b, l, d, n, dtype)
+    real = jnp.arange(l) < l - pad
+    u = jnp.where(real[None, :, None], u, 0).astype(dtype)
+    dt = jnp.where(real[None, :, None], dt, 0)
     yr, hr = selective_scan_ref(u, dt, a, bb, c, dd, h0)
-    yk, hk = selective_scan_pallas(u, dt, a, bb, c, dd, h0, bd=8, bl=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), rtol=2e-5, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), rtol=2e-5, atol=1e-4)
+    yk, hk = selective_scan(u, dt, a, bb, c, dd, h0, bd=bd, bl=bl, interpret=True)
+    _assert_scan_close(yk, hk, yr, hr)
+    if pad:
+        r = l - pad
+        _, h_real = selective_scan_ref(u[:, :r], dt[:, :r], a, bb[:, :r], c[:, :r], dd, h0)
+        np.testing.assert_allclose(np.asarray(hk), np.asarray(h_real), rtol=2e-5, atol=1e-4)
     ys, h = [], h0
     for lo, hi in ((0, split), (split, l)):
         if hi > lo:
             y, h = selective_scan(u[:, lo:hi], dt[:, lo:hi], a, bb[:, lo:hi], c[:, lo:hi], dd, h,
-                                  bd=8, bl=16, interpret=True)
+                                  bd=bd, bl=2 * bl, interpret=True)
             ys.append(y)
-    np.testing.assert_allclose(np.asarray(jnp.concatenate(ys, 1)), np.asarray(yr),
-                               rtol=2e-5, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=2e-5, atol=1e-4)
+    _assert_scan_close(jnp.concatenate(ys, 1), h, yr, hr)
 
 
 def test_selective_step_matches_scan():
