@@ -23,8 +23,9 @@ with random weights made from ``--seed``:
 With ``--chips 4`` the script runs only what exists across chips, at the
 same widths with the depth cut to 2 layers: ShardedPopulationEngine on a
 4x1 and a 2x2 mesh against the one-device population engine, and
-ShardedFleetServeEngine against four per-chip engines, and it checks that
-their chip-stacked state spans four devices.
+ShardedFleetServeEngine (warmed up, nothing compiling while it serves)
+against four per-chip engines, and it checks that the chip-stacked state
+and the fleet's served weights span four devices.
 
 Each phase prints one line of results with its set-up seconds (compilation
 included) and its run seconds; these are smoke timings, not benchmark
@@ -499,12 +500,13 @@ class Smoke:
         kw = dict(num_slots=2, page_size=16, num_pages=2 * 21 + 1, max_pages_per_seq=21)
         t0 = time.perf_counter()
         fleet = ShardedFleetServeEngine(cfg, [params] * 4, ctxs, **kw)
-        placed = {name: len(self._devices_of(x))
-                  for name, x in (("params", fleet.params), ("fault_masks", fleet.ctx.ok))}
-        check(all(n == 4 for n in placed.values()),
-              f"chip-stacked fleet state spans {placed} devices, not 4 each")
+        placed = len(self._devices_of(fleet.served_params))
+        check(placed == 4, f"the fleet's served weights span {placed} devices, not 4")
+        aot = fleet.warmup()
         outs, stats = fleet.serve(streams)
         fleet_s = time.perf_counter() - t0
+        fallback = fleet.compile_counts()["jit_fallback"]
+        check(fallback == 0, f"{fallback} fleet programs compiled after warmup")
 
         rtol, atol = dtype_tol(cfg.dtype)
         agree = total = 0
@@ -522,7 +524,7 @@ class Smoke:
                 total += len(w.tokens)
         ref_s = time.perf_counter() - t0
         self.report("4.fleet_serve", model=cfg.name, layers=cfg.num_layers, chips=4,
-                    placement=placed, tokens=stats.emitted_tokens,
+                    placement=placed, aot_programs=aot, tokens=stats.emitted_tokens,
                     fused_dispatches=stats.decode_dispatches,
                     greedy_agreement=f"{agree}/{total}={agree / total:.3f}",
                     fleet_s_with_compile=f"{fleet_s:.3f}",

@@ -12,27 +12,21 @@ Three cooperating modules (see README.md in this directory):
   ``wasted_steps`` accounting keyed on the pop-axis extent.
 * :mod:`repro.fleet.capacity` — :func:`suggest_population_size`, sizing
   population lanes against per-device memory from param/opt bytes.
-* :mod:`repro.fleet.serve` — :class:`FleetServeEngine`, one vmapped serving
-  engine advancing N faulty chips' deployed models a token per dispatch, and
-  :class:`ShardedFleetServeEngine`, continuous-batch fleet serving under
-  ``shard_map`` over the pop mesh — one ragged request stream and paged-KV
-  slot table per chip.
+* :mod:`repro.fleet.serve` — :class:`ShardedFleetServeEngine`, the
+  continuous-batching engine over a chip axis placed on the pop mesh: one
+  ragged request stream and paged-KV slot table per chip, the single
+  engine's programs and round loop, one ``shard_map`` decode dispatch for
+  the whole fleet.
 """
 from repro.fleet.capacity import suggest_population_size
 from repro.fleet.scheduler import FleetSchedule, FleetScheduler, ScheduledChunk
-from repro.fleet.serve import (
-    FleetGenerateResult,
-    FleetServeEngine,
-    ShardedFleetServeEngine,
-)
+from repro.fleet.serve import ShardedFleetServeEngine
 from repro.fleet.sharding import ShardedPopulationEngine
 
 __all__ = [
     "FleetSchedule",
     "FleetScheduler",
     "ScheduledChunk",
-    "FleetGenerateResult",
-    "FleetServeEngine",
     "ShardedFleetServeEngine",
     "ShardedPopulationEngine",
     "suggest_population_size",

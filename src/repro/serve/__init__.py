@@ -1,6 +1,6 @@
 """repro.serve — the serving subsystem (see README.md in this directory).
 
-Three engine tiers over one fused sampling+decode step:
+Two serving engines over one fused sampling+decode step:
 
 * :mod:`repro.serve.engine` — :class:`ServeEngine`, static-batch generation
   (one rectangular prompt batch, dense KV cache, optional EOS masking).
@@ -8,9 +8,9 @@ Three engine tiers over one fused sampling+decode step:
   request queue + slot table over the paged KV cache
   (:mod:`repro.serve.kvcache`): admit into free slots, retire on EOS or
   budget, pages freed mid-flight.
-* :mod:`repro.fleet.serve` — the fleet tiers: ``FleetServeEngine`` (vmap,
-  shared prompts) and ``ShardedFleetServeEngine`` (shard_map over the pop
-  mesh, one ragged request stream per chip).
+  :mod:`repro.fleet.serve`'s ``ShardedFleetServeEngine`` is this engine
+  over a chip axis placed on the pop mesh: the same programs and round
+  loop, one ragged request stream and slot table per chip.
 """
 from repro.serve.continuous import (
     ContinuousBatchingEngine,

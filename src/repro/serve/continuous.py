@@ -100,6 +100,13 @@ __all__ = [
 ]
 
 
+# where each program's loop-carried operands sit: the donated ones, and on
+# a fleet the chip-stacked ones beside the weights
+_DECODE_CARRIED = (1, 2, 3, 6, 8)  # cur, cache, key, active, remaining
+_ADMIT_CARRIED = (5, 6, 7, 8)  # cache, cur, active, remaining
+_CHUNK_CARRIED = (3, 4, 5, 6)  # cache, cur, active, remaining
+
+
 def shape_structs(tree):
     """ShapeDtypeStruct mirror of a pytree — AOT lowering without arrays."""
     return jax.tree_util.tree_map(
@@ -313,6 +320,25 @@ class _SlotTable:
         return retired
 
 
+class _Lane:
+    """One chip's host side of a serve: its slot table, tracer and page
+    monitor on its tracks, its pack and enqueue times, its operand to the
+    admission programs and its row of the decode outputs."""
+
+    def __init__(self, chip, table, rec, proc, *, prefix="", operand=(), row=...):
+        self.chip = chip
+        self.table = table
+        self.prefix = prefix  # track prefix: "" on one chip, "chip{c}/" on a fleet
+        self.tracer = RequestTracer(rec, proc=proc, track_prefix=prefix)
+        self.pool = PoolMonitor(rec, table.alloc, proc=proc, track=f"{prefix}pages",
+                                name_prefix="kv." + prefix.replace("/", "."))  # kv.chip{c}.
+        self.operand = operand
+        self.row = row
+        self.pack: list[PackItem] = []
+        self.enqueued: dict = {}  # rid -> its enqueue time, until its first prefill dispatch
+        self.mask = table.active  # the slots the last decode dispatch ran
+
+
 class ContinuousBatchingEngine:
     """Continuous batching on one chip: paged KV + slot table + one fused
     masked decode step per token across all in-flight requests, admitted
@@ -322,7 +348,14 @@ class ContinuousBatchingEngine:
     admission program per distinct prompt length — the unbucketed baseline
     ``benchmarks/serve_bench.py --heavy-traffic`` measures against). A
     model with SSM layers packs one prompt per admission (``max_pack`` 1).
+
+    The round loop (``_rounds``) runs over one slot table per chip: one
+    here, one per chip in ``repro.fleet.serve.ShardedFleetServeEngine``.
     """
+
+    num_chips = 1
+    _proc = "serve"  # the recorder's process lane
+    _chip_operand = ()  # the admission programs' trailing chip operand, as warmup() shapes it
 
     def __init__(
         self,
@@ -343,14 +376,28 @@ class ContinuousBatchingEngine:
         health_config: Optional[HealthConfig] = None,
         alert_rules: Optional[Sequence[AlertRule]] = None,
     ):
-        M.check_pageable(cfg, "continuous batching")
-        if num_slots < 1:
-            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-        self.cfg = cfg
         # the weights as given: the prober's and every premask's source
         self.params = params
         # the LIVE fault context: set_silicon's checks and the prober read it
         self.ctx = ctx or healthy()
+        self._start(
+            cfg, num_slots=num_slots, page_size=page_size, num_pages=num_pages,
+            max_pages_per_seq=max_pages_per_seq, pad_id=pad_id,
+            prefill_buckets=prefill_buckets, chunk_size=chunk_size, max_pack=max_pack,
+            recorder=recorder, probe_every=probe_every, health_config=health_config,
+            alert_rules=alert_rules,
+        )
+
+    def _start(
+        self, cfg, *, num_slots, page_size, num_pages, max_pages_per_seq, pad_id,
+        prefill_buckets, chunk_size, max_pack, recorder, probe_every, health_config,
+        alert_rules,
+    ) -> None:
+        """Build the programs, served weights and fault detection."""
+        M.check_pageable(cfg, "continuous batching")
+        if num_slots < 1:
+            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        self.cfg = cfg
         self.num_slots = num_slots
         self.page_size = page_size
         self.num_pages = num_pages
@@ -378,27 +425,13 @@ class ContinuousBatchingEngine:
                 raise ValueError(f"max_pack must be >= 1, got {max_pack}")
             # one scan runs along a packed row: SSM state would carry over
             self.max_pack = 1 if cfg.has_ssm else int(max_pack)
-        # every loop-carried operand (cur logits, paged cache, key, active
-        # mask, remaining budgets) is re-bound from the previous dispatch's
-        # outputs — donate them all so the page pool never round-trips
-        # through a copy (repro.analysis DON001); params/ctx/eos and the
-        # host-built pack/chunk index maps are reused or rebuilt per call
-        # and stay undonated
-        self._sample_decode = jax.jit(
-            make_sample_decode(cfg, pad_id=pad_id), donate_argnums=(1, 2, 3, 6, 8)
-        )
-        self._packed_admit = jax.jit(
-            self._packed_admit_fn, donate_argnums=(5, 6, 7, 8)
-        )
-        self._prefill_chunk = jax.jit(
-            self._prefill_chunk_fn, donate_argnums=(3, 4, 5, 6)
-        )
+        self._sample_decode, self._packed_admit, self._prefill_chunk = self._programs()
         # what the serving programs run on: on a faulty chip the premasked
         # weights and a context that masks nothing; on a healthy chip the
         # params as given
         self._serve_ctx = healthy()
         self._premask = jax.jit(self._premask_fn)
-        self.served_params = self._premasked() if self.ctx.active else params
+        self.served_params = self._served_weights()
         # AOT-compiled executables by program key — see warmup(); dispatch
         # prefers these, falling back to the jit wrappers above (whose
         # _cache_size() then counts traffic-time compiles)
@@ -414,47 +447,72 @@ class ContinuousBatchingEngine:
         self.scan_ops: dict[str, list[str]] = {}
         if cfg.has_ssm:
             self.obs.count("ssm.state_bytes", ssm_state_bytes(cfg, num_slots))
-        # fault detection (ROADMAP item 2): an ABFT prober dispatched every
-        # probe_every decode dispatches, feeding the health state machine
-        # and the alert engine. Probes are SEPARATE dispatches through a
-        # separate jitted program (outside compile_counts()/used_programs)
-        # and never touch the serve loop's carried state or key stream, so
-        # the PR-8 guarantee holds: enabling them changes no sampled token.
+        # fault detection (ROADMAP item 2): an ABFT prober per chip
+        # dispatched every probe_every decode dispatches, feeding the health
+        # state machine and the alert engine. Probes are SEPARATE dispatches
+        # through a separate jitted program (outside compile_counts()/
+        # used_programs) and never touch the serve loop's carried state or
+        # key stream, so the PR-8 guarantee holds: enabling them changes no
+        # sampled token.
         if probe_every is not None and probe_every < 1:
             raise ValueError(f"probe_every must be >= 1, got {probe_every}")
         self.probe_every = int(probe_every) if probe_every else None
-        self.prober = None
+        self._probers: Optional[list] = None
         self.health: Optional[HealthTracker] = None
         self.alerts = AlertEngine(self.obs, alert_rules) if alert_rules else None
         if self.probe_every:
-            self._init_prober(health_config)
+            self._init_probers(health_config)
 
-    def _init_prober(self, health_config: Optional[HealthConfig]) -> None:
+    def _programs(self):
+        """The jitted decode, packed-admit and chunk programs."""
+        # every loop-carried operand (cur logits, paged cache, key, active
+        # mask, remaining budgets) is re-bound from the previous dispatch's
+        # outputs — donate them all so the page pool never round-trips
+        # through a copy (repro.analysis DON001); params/ctx/eos and the
+        # host-built pack/chunk index maps are reused or rebuilt per call
+        # and stay undonated
+        return (
+            jax.jit(make_sample_decode(self.cfg, pad_id=self.pad_id),
+                    donate_argnums=_DECODE_CARRIED),
+            jax.jit(self._packed_admit_fn, donate_argnums=_ADMIT_CARRIED),
+            jax.jit(self._prefill_chunk_fn, donate_argnums=_CHUNK_CARRIED),
+        )
+
+    def _served_weights(self):
+        return self._premasked(self.params, self.ctx.ok) if self.ctx.active else self.params
+
+    def _silicon(self, chip: int):
+        """Chip ``chip``'s raw weights and LIVE fault context."""
+        return self.params, self.ctx
+
+    def _init_probers(self, health_config: Optional[HealthConfig]) -> None:
         from repro.kernels.masked_matmul.ops import masked_matmul_checksummed
         from repro.obs.abft import ChipProber, select_probe_weight
 
         cfg = self.cfg
         rows, cols = cfg.array_rows, cfg.array_cols
-        name, w = select_probe_weight(self.params)
-        probe_fn = jax.jit(masked_matmul_checksummed)
+        probe_fn = jax.jit(masked_matmul_checksummed)  # one compile for every chip
         ones = jnp.ones((rows, cols), jnp.float32)
         dtype = jnp.dtype(cfg.dtype)
 
-        def dispatch(x):
-            # the LIVE mask: re-read self.ctx so a set_silicon() change is
-            # what the next probe computes through (same shape, no recompile)
-            ok = self.ctx.ok if self.ctx.ok is not None else ones
-            y, chk = probe_fn(jnp.asarray(x, dtype), w, ok)
-            return np.asarray(y), np.asarray(chk)
+        def prober(c):
+            _, w = select_probe_weight(self._silicon(c)[0])
 
-        self._probe_weight = name
-        # snapshotting compiles the probe program and records goldens under
-        # the believed map — before traffic, so probes never jit mid-serve
-        self.prober = ChipProber(
-            dispatch, array_shape=(rows, cols), k_dim=int(w.shape[0])
-        )
+            def dispatch(x):
+                # chip c's LIVE mask: re-read it so a set_silicon() change is
+                # what the next probe computes through (same shape, no recompile)
+                ok = self._silicon(c)[1].ok
+                y, chk = probe_fn(jnp.asarray(x, dtype), w, ok if ok is not None else ones)
+                return np.asarray(y), np.asarray(chk)
+
+            # snapshotting compiles the probe program and records goldens
+            # under the believed map — before traffic, so probes never jit
+            # mid-serve
+            return ChipProber(dispatch, array_shape=(rows, cols), k_dim=int(w.shape[0]), chip=c)
+
+        self._probers = [prober(c) for c in range(self.num_chips)]
         self.health = HealthTracker(
-            1, self.obs, config=health_config, proc="serve"
+            self.num_chips, self.obs, config=health_config, proc=self._proc
         )
 
     def set_silicon(self, ctx: FaultContext) -> None:
@@ -468,21 +526,26 @@ class ContinuousBatchingEngine:
         ``FaultMap`` context models pristine silicon): a healthy engine
         serves the raw params, and its AOT executables were compiled for
         their pytree structure, which has no tied ``lm_head``."""
-        cur = self.ctx
-        if cur.ok is None or ctx is None or ctx.ok is None:
+        self.served_params = self._resilicon(self.params, ctx, like=self.ctx)
+        self.ctx = ctx
+
+    def _resilicon(self, params, ctx: FaultContext, *, like: Optional[FaultContext]):
+        """Check a silicon change against ``like``, an active context the
+        engine was built with (None: it premasks nothing), and return
+        ``params`` premasked under ``ctx``."""
+        if like is None or not like.active or ctx is None or not ctx.active:
             raise ValueError(
                 "set_silicon needs ACTIVE fault contexts on both sides; "
                 "construct the engine with an explicit (possibly zero-fault)"
                 " FaultMap context so its served weights are premasked"
             )
-        if cur.mode != ctx.mode or tuple(cur.ok.shape) != tuple(ctx.ok.shape):
+        if like.mode != ctx.mode or tuple(like.ok.shape) != tuple(ctx.ok.shape):
             raise ValueError(
                 f"silicon change must keep mode/shape: have "
-                f"{cur.mode}/{tuple(cur.ok.shape)}, "
+                f"{like.mode}/{tuple(like.ok.shape)}, "
                 f"got {ctx.mode}/{tuple(ctx.ok.shape)}"
             )
-        self.ctx = ctx
-        self.served_params = self._premasked()
+        return self._premasked(params, ctx.ok)
 
     # -- jitted pieces ------------------------------------------------------
 
@@ -494,19 +557,19 @@ class ContinuousBatchingEngine:
             gemm = [masked_weight(w, ok) for w in gemm]
             return gemm, None if embed is None else masked_weight(embed.T, ok)
 
-    def _premasked(self):
-        """Run the premask program under the live context; the served params
-        share every other leaf (the embedding the lookup reads, the norms)
-        with the raw ones."""
+    def _premasked(self, params, ok):
+        """Run the premask program on ``params`` under healthy-PE mask
+        ``ok``; the served params share every other leaf (the embedding the
+        lookup reads, the norms) with the raw ones."""
         rec = self.obs
-        flat, treedef = jax.tree_util.tree_flatten_with_path(self.params)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
         ix = [i for i, (path, w) in enumerate(flat) if is_array_mapped(path, w)]
-        embed = self.params["embed"] if self.cfg.tie_embeddings else None
-        with rec.timed("premask", proc="serve", track="host",
+        embed = params["embed"] if self.cfg.tie_embeddings else None
+        with rec.timed("premask", proc=self._proc, track="host",
                        annotate=jax.profiler.TraceAnnotation):
             # one mask dtype, so a set_silicon map of another reuses the program
             gemm, head = self._premask(
-                [flat[i][1] for i in ix], embed, jnp.asarray(self.ctx.ok, jnp.float32))
+                [flat[i][1] for i in ix], embed, jnp.asarray(ok, jnp.float32))
             jax.block_until_ready((gemm, head))
         rec.count("fault_mask.premask")
         leaves = [w for _, w in flat]
@@ -602,6 +665,15 @@ class ContinuousBatchingEngine:
         remaining = i32(self.num_slots)
         return cache, cur, active, remaining
 
+    def _init_state(self, key):
+        """The loop-carried state a serve starts from: the zero paged cache,
+        slot logits, active mask and budgets, and the sampling key."""
+        zeros = lambda s: jnp.zeros(s.shape, s.dtype)
+        return (*jax.tree_util.tree_map(zeros, self._state_structs()), self._sample_keys(key))
+
+    def _sample_keys(self, key):
+        return key
+
     def warmup(self) -> int:
         """AOT-precompile the closed program set before traffic arrives:
         one packed-admit program per bucket, the chunk program, and the
@@ -634,6 +706,7 @@ class ContinuousBatchingEngine:
         params_s = shape_structs(self.served_params)
         ctx_s = shape_structs(self._serve_ctx)
         cache, cur, active, remaining = self._state_structs()
+        chip = shape_structs(self._chip_operand)
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
         K, maxp = self.max_pack, self.max_pages_per_seq
         for w in self.prefill_buckets:
@@ -642,7 +715,7 @@ class ContinuousBatchingEngine:
                 self._aot[key] = self._packed_admit.lower(
                     params_s, i32(1, w), i32(1, w), i32(1, w), ctx_s,
                     cache, cur, active, remaining,
-                    i32(w), i32(w), i32(K), i32(K), i32(K, maxp), i32(K), i32(K),
+                    i32(w), i32(w), i32(K), i32(K), i32(K, maxp), i32(K), i32(K), *chip,
                 ).compile()
         c = self.chunk_size
         key = ("prefill_chunk", c)
@@ -650,13 +723,13 @@ class ContinuousBatchingEngine:
             self._aot[key] = self._prefill_chunk.lower(
                 params_s, i32(1, c), ctx_s, cache, cur, active, remaining,
                 i32(), i32(maxp), i32(c), i32(c), i32(), i32(), i32(),
-                jax.ShapeDtypeStruct((), jnp.bool_),
+                jax.ShapeDtypeStruct((), jnp.bool_), *chip,
             ).compile()
         key = ("decode",)
         if key not in self._aot:
             self._aot[key] = self._sample_decode.lower(
-                params_s, cur, cache, shape_structs(jax.random.PRNGKey(0)), ctx_s,
-                jax.ShapeDtypeStruct((), jnp.float32), active, i32(), remaining,
+                params_s, cur, cache, shape_structs(self._sample_keys(jax.random.PRNGKey(0))),
+                ctx_s, jax.ShapeDtypeStruct((), jnp.float32), active, i32(), remaining,
             ).compile()
 
     def compile_counts(self) -> dict:
@@ -697,35 +770,34 @@ class ContinuousBatchingEngine:
             return {}, ServeStats(num_slots=self.num_slots, page_size=self.page_size)
         alloc = PageAllocator(self.num_pages, self.page_size)
         table = _SlotTable(requests, self.num_slots, alloc, self.max_pages_per_seq)
-        stats = ServeStats(num_slots=self.num_slots, page_size=self.page_size)
+        stats = self._rounds((_Lane(0, table, self.obs, self._proc),), temperature=temperature,
+                             eos_id=eos_id, key=key, on_step=on_step)
+        return table.outputs, stats
+
+    def _rounds(self, lanes, *, temperature, eos_id, key, on_step) -> ServeStats:
+        """The round loop, over one lane per chip, until every chip's
+        stream is served. A round admits, packs and streams chunks for
+        each chip, runs one decode dispatch for all of them, then records
+        each chip's step, health, probes and alerts."""
+        stats = ServeStats(num_slots=self.num_chips * self.num_slots, page_size=self.page_size)
         rec = self.obs
-        tracer = RequestTracer(rec, proc="serve")
-        pool = PoolMonitor(rec, alloc, proc="serve")
-        enqueued: dict = {}  # rid -> its enqueue time, until its first prefill dispatch
+        proc = self._proc
+        tracer = RequestTracer(rec, proc=proc)
         # each stage of a round is a span on the `host` track and, under
         # the same name, a profiler annotation on the device trace's clock
-        stage = partial(rec.timed, proc="serve", track="host",
+        stage = partial(rec.timed, proc=proc, track="host",
                         annotate=jax.profiler.TraceAnnotation)
         if rec:
-            rec.instant("serve.programs", proc="serve", track="engine",
+            rec.instant("serve.programs", proc=proc, track="engine",
                         args={MASK_SCOPE: self.mask_ops, MAMBA_SCOPE: self.mamba_ops,
                               SCAN_SCOPE: self.scan_ops})
 
-        V = self.cfg.vocab_size
-        dtype = jnp.dtype(self.cfg.dtype)
-        cache = M.init_paged_cache(
-            self.cfg, self.num_pages, self.page_size, self.num_slots,
-            self.max_pages_per_seq,
-        )
-        cur = jnp.zeros((self.num_slots, V), dtype)
-        active = jnp.zeros((self.num_slots,), bool)
-        remaining = jnp.zeros((self.num_slots,), jnp.int32)
-        key = key if key is not None else jax.random.PRNGKey(0)
+        cache, cur, active, remaining, key = self._init_state(
+            key if key is not None else jax.random.PRNGKey(0))
         temp = jnp.float32(temperature)
         eos = jnp.asarray(-1 if eos_id is None else eos_id, jnp.int32)
         buckets = self.prefill_buckets
         top = buckets[-1] if buckets else None
-        pack: list[PackItem] = []
 
         def dispatch(pkey, jitted, *args):
             """Run program ``pkey``: its AOT executable, else the jit
@@ -737,13 +809,14 @@ class ContinuousBatchingEngine:
                 n = jitted._cache_size()
                 out = jitted(*args)
                 if rec and jitted._cache_size() > n:
-                    rec.instant("compile.fallback", proc="serve", track="engine",
+                    rec.instant("compile.fallback", proc=proc, track="engine",
                                 args=dict(program=str(pkey), clock=clock))
             self.used_programs.add(pkey)
             return out
 
-        def flush_pack():
+        def flush_pack(lane):
             nonlocal cache, cur, active, remaining
+            pack = lane.pack
             if not pack:
                 return
             total = sum(len(it.tokens) for it in pack)
@@ -760,7 +833,7 @@ class ContinuousBatchingEngine:
                 arrays["segments"], self._serve_ctx, cache, cur, active, remaining,
                 arrays["page_ix"], arrays["page_off"], arrays["gather_pos"],
                 arrays["slots"], arrays["rows"], arrays["seq_lens"],
-                arrays["budgets"],
+                arrays["budgets"], *lane.operand,
             )
             stats.prefill_dispatches += 1
             if rec:
@@ -768,15 +841,15 @@ class ContinuousBatchingEngine:
                     jax.block_until_ready(cur)
                 t1 = rec.now()
                 for it in pack:
-                    tracer.queued(it.rid, it.slot, enqueued.pop(it.rid), t0)
-                    tracer.admitted(
+                    lane.tracer.queued(it.rid, it.slot, lane.enqueued.pop(it.rid), t0)
+                    lane.tracer.admitted(
                         it.rid, it.slot, t0, t1,
                         args=dict(bucket=width, packed=len(pack),
                                   prompt_len=len(it.tokens)),
                     )
             pack.clear()
 
-        def run_chunks(slot, r, pages):
+        def run_chunks(lane, slot, r, pages):
             nonlocal cache, cur, active, remaining
             steps = plan_prefill(
                 len(r.tokens), buckets=buckets, chunk_size=self.chunk_size
@@ -794,75 +867,81 @@ class ContinuousBatchingEngine:
                     self.served_params, ct[None], self._serve_ctx, cache, cur, active,
                     remaining, np.int32(slot), row, maps["page_ix"],
                     maps["page_off"], np.int32(st.start), np.int32(st.valid),
-                    np.int32(r.max_new_tokens), np.bool_(st.final),
+                    np.int32(r.max_new_tokens), np.bool_(st.final), *lane.operand,
                 )
                 stats.prefill_dispatches += 1
                 stats.chunk_dispatches += 1
                 if rec:
                     with stage("prefill.wait"):
                         jax.block_until_ready(cur)
-                    if r.rid in enqueued:  # its first chunk
-                        tracer.queued(r.rid, slot, enqueued.pop(r.rid), t0)
-                    tracer.chunk(
+                    if r.rid in lane.enqueued:  # its first chunk
+                        lane.tracer.queued(r.rid, slot, lane.enqueued.pop(r.rid), t0)
+                    lane.tracer.chunk(
                         r.rid, slot, t0, rec.now(), final=st.final,
                         args=dict(size=st.size, start=st.start, valid=st.valid),
                     )
 
         clock = 0  # decode-dispatch index
-        while not table.done:
+        while not all(lane.table.done for lane in lanes):
             if on_step is not None:
                 on_step(clock)
             round_annotation = partial(jax.profiler.StepTraceAnnotation, step_num=clock)
-            with rec.timed("serve_round", proc="serve", track="host",
+            with rec.timed("serve_round", proc=proc, track="host",
                            args=dict(clock=clock), annotate=round_annotation):
                 with stage("schedule"):
-                    table.stamp_arrivals(clock)
-                    if rec:
-                        for r in table.pending:
-                            if r.arrival > clock:
-                                break  # pending is arrival-sorted
-                            if r.rid not in enqueued:
-                                enqueued[r.rid] = rec.instant(
-                                    "enqueue", proc="serve", track="engine",
-                                    args=dict(rid=r.rid, arrival=r.arrival, clock=clock))
-                    # admissions: fill free slots with every arrived request we
-                    # can, packing short prompts into shared bucket dispatches
-                    while True:
-                        adm = table.pop_admission(clock)
-                        if adm is None:
-                            break
-                        slot, r, pages = adm
-                        table.outputs_admitted[r.rid] = clock
-                        stats.admitted += 1
-                        if self.cfg.has_ssm:
-                            rec.count("ssm.state_reset")
-                        plen = len(r.tokens)
-                        if top is not None and plen > top:
-                            flush_pack()
-                            run_chunks(slot, r, pages)
-                            continue
-                        if pack and (
-                            len(pack) >= self.max_pack
-                            or (top is not None and sum(len(i.tokens) for i in pack) + plen > top)
-                        ):
-                            flush_pack()
-                        pack.append(
-                            PackItem(np.asarray(r.tokens, np.int32), slot, tuple(pages),
-                                     r.max_new_tokens, rid=r.rid)
-                        )
-                    flush_pack()
+                    in_use = n_active = 0
+                    for lane in lanes:
+                        table = lane.table
+                        table.stamp_arrivals(clock)
+                        if rec:
+                            for r in table.pending:
+                                if r.arrival > clock:
+                                    break  # pending is arrival-sorted
+                                if r.rid not in lane.enqueued:
+                                    lane.enqueued[r.rid] = rec.instant(
+                                        "enqueue", proc=proc, track=f"{lane.prefix}engine",
+                                        args=dict(rid=r.rid, arrival=r.arrival, clock=clock))
+                        # admissions: fill free slots with every arrived request
+                        # we can, packing short prompts into shared bucket
+                        # dispatches
+                        while True:
+                            adm = table.pop_admission(clock)
+                            if adm is None:
+                                break
+                            slot, r, pages = adm
+                            table.outputs_admitted[r.rid] = clock
+                            stats.admitted += 1
+                            if self.cfg.has_ssm:
+                                rec.count("ssm.state_reset")
+                            plen = len(r.tokens)
+                            if top is not None and plen > top:
+                                flush_pack(lane)
+                                run_chunks(lane, slot, r, pages)
+                                continue
+                            if lane.pack and (
+                                len(lane.pack) >= self.max_pack
+                                or (top is not None
+                                    and sum(len(i.tokens) for i in lane.pack) + plen > top)
+                            ):
+                                flush_pack(lane)
+                            lane.pack.append(
+                                PackItem(np.asarray(r.tokens, np.int32), slot, tuple(pages),
+                                         r.max_new_tokens, rid=r.rid)
+                            )
+                        flush_pack(lane)
+                        in_use += table.alloc.pages_in_use
+                        n_active += int(table.active.sum())
+                        lane.pool.sample()
                     stats.peak_resident_kv_bytes = max(
-                        stats.peak_resident_kv_bytes, alloc.pages_in_use * self._page_bytes
+                        stats.peak_resident_kv_bytes, in_use * self._page_bytes
                     )
-                    pool.sample()
-                if not table.active.any():
+                if not n_active:
                     # idle: jump the clock to the next arrival (no dispatches)
-                    nxt = table.next_arrival()
-                    assert nxt is not None and nxt > clock
+                    nxt = min(lane.table.next_arrival() for lane in lanes if lane.table.pending)
+                    assert nxt > clock
                     clock = nxt
                     continue
 
-                n_active = int(table.active.sum())
                 t0 = rec.now() if rec else 0.0
                 with stage("decode.dispatch"):
                     emitted, tok_lp, cur, cache, key, active, remaining = dispatch(
@@ -874,7 +953,7 @@ class ContinuousBatchingEngine:
                 stats.decode_dispatches += 1
                 stats.emitted_tokens += n_active
                 stats.active_slot_steps += n_active
-                stats.kv_byte_steps += alloc.pages_in_use * self._page_bytes
+                stats.kv_byte_steps += in_use * self._page_bytes
                 with stage("decode.wait"):
                     jax.block_until_ready(emitted)
                 with stage("decode.fetch"):
@@ -882,40 +961,45 @@ class ContinuousBatchingEngine:
                     lp = np.asarray(tok_lp)
                     ac = np.asarray(active)
                 t1 = rec.now() if rec else 0.0
-                msk = table.active  # the mask this dispatch computed under
                 with stage("record_step"):
                     if rec:
                         tracer.decode_dispatch(t0, t1, n_active=n_active, clock=clock)
-                        slot_of = {r.rid: s for s, r in enumerate(table.slots)
-                                   if r is not None}
-                    retired = table.record_step(em, lp, ac, clock, eos_id=eos_id)
-                    if rec and retired:
-                        t1 = rec.now()
-                        for rid in retired:
-                            tracer.retired(table.outputs[rid], slot_of[rid], t1)
-                        pool.sample()
+                    for lane in lanes:
+                        table, row = lane.table, lane.row
+                        lane.mask = table.active  # the mask this dispatch computed under
+                        if rec:
+                            slot_of = {r.rid: s for s, r in enumerate(table.slots)
+                                       if r is not None}
+                        retired = table.record_step(em[row], lp[row], ac[row], clock,
+                                                    eos_id=eos_id)
+                        if rec and retired:
+                            t1 = rec.now()
+                            for rid in retired:
+                                lane.tracer.retired(table.outputs[rid], slot_of[rid], t1)
+                            lane.pool.sample()
                 if self.health is not None:
                     with stage("health"):
-                        self.health.observe_decode(
-                            0, clock=clock,
-                            mean_logprob=float(lp[msk].mean()) if msk.any() else None,
-                            alloc_failures=alloc.alloc_failures,
-                        )
-                        if self.prober is not None and clock % self.probe_every == 0:
-                            t0p = rec.now() if rec else 0.0
-                            res = self.prober.probe(clock=clock)
-                            stats.probe_dispatches += res.dispatches
-                            if rec:
-                                rec.span("probe", proc="serve", track="health",
-                                         t0=t0p, t1=rec.now(), args=res.as_dict())
-                                rec.count("probe.dispatches", res.dispatches)
-                            self.health.observe_probe(0, res, clock=clock)
+                        for lane in lanes:
+                            msk = lane.mask
+                            self.health.observe_decode(
+                                lane.chip, clock=clock,
+                                mean_logprob=float(lp[lane.row][msk].mean()) if msk.any() else None,
+                                alloc_failures=lane.table.alloc.alloc_failures,
+                            )
+                        if self._probers is not None and clock % self.probe_every == 0:
+                            for lane in lanes:
+                                t0p = rec.now() if rec else 0.0
+                                res = self._probers[lane.chip].probe(clock=clock)
+                                stats.probe_dispatches += res.dispatches
+                                if rec:
+                                    rec.span("probe", proc=proc, track=f"{lane.prefix}health",
+                                             t0=t0p, t1=rec.now(), args=res.as_dict())
+                                    rec.count("probe.dispatches", res.dispatches)
+                                self.health.observe_probe(lane.chip, res, clock=clock)
                             if self.alerts:
                                 self.alerts.evaluate(clock=clock)
-        stats.peak_resident_kv_bytes = max(
-            stats.peak_resident_kv_bytes, alloc.peak_pages * self._page_bytes
-        )
-        pool.flush()  # close the counter series at the final timestamp
+        for lane in lanes:
+            lane.pool.flush()  # close the counter series at the final timestamp
         if self.health is not None:
             self.health.finalize()
         if self.alerts:
@@ -925,6 +1009,6 @@ class ContinuousBatchingEngine:
             rec.gauge_set("serve.compiles.aot", cc["aot"])
             rec.gauge_set("serve.compiles.jit_fallback", cc["jit_fallback"])
             rec.gauge_set("serve.compiles.total", cc["total"])
-            rec.instant("serve.end", proc="serve", track="engine",
+            rec.instant("serve.end", proc=proc, track="engine",
                         args=stats.as_dict())
-        return table.outputs, stats
+        return stats

@@ -12,12 +12,15 @@ covers greedy and every temperature); greedy token choice is exactly
 ``argmax`` — independent of the sampling key — so temperature=0.0
 reproduces the unfused reference token-for-token.
 
-The same fused step powers every serving tier (see README.md here):
-``ServeEngine`` jits it, ``repro.fleet.serve.FleetServeEngine`` vmaps it
-over chips, and the continuous-batching engines (``repro.serve.continuous``
-and ``repro.fleet.serve.ShardedFleetServeEngine``) run its *masked* form —
-per-slot ``active`` masking over a paged cache — so finished slots emit pad
-tokens with logprob 0 and stop writing KV until the scheduler refills them.
+The same fused step powers both serving engines (see README.md here):
+``ServeEngine`` jits it, and the continuous-batching engine
+(``repro.serve.continuous``; ``repro.fleet.serve.ShardedFleetServeEngine``
+is it over a chip axis, the step vmapped over each device's chips) runs its
+*masked* form — per-slot ``active`` masking over a paged cache — so
+finished slots emit pad tokens with logprob 0 and stop writing KV until the
+scheduler refills them. ``ServeEngine`` masks a faulty chip's weights
+inside every GEMM; it is the reference the continuous engine's premasked
+serving is pinned against.
 """
 from __future__ import annotations
 
@@ -50,9 +53,8 @@ def make_sample_decode(cfg, *, pad_id: int = 0):
     (next_token, token_logprob, next_logits, cache, key)`` — log_softmax,
     the greedy/categorical choice, the chosen-token logprob gather and the
     next ``decode_step`` in a single traced body. ``ServeEngine`` jits it
-    directly (one dispatch per token); ``repro.fleet.serve.FleetServeEngine``
-    vmaps it over N chips' (params, FaultContext) pairs first, so a whole
-    fleet advances one token per dispatch.
+    directly (one dispatch per token); the fleet engine vmaps it over the
+    chips on each device, so a whole fleet advances one token per dispatch.
 
     With ``active`` (a per-slot bool mask) the step runs in *masked* form
     and returns ``(emitted, token_logprob, next_logits, cache, key,

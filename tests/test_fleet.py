@@ -1,5 +1,5 @@
-"""Fleet subsystem tests (repro.fleet): budget-aware scheduling, device-
-sharded population execution, and multi-chip serving.
+"""Fleet subsystem tests (repro.fleet): budget-aware scheduling and device-
+sharded population execution (fleet serving: tests/test_serve_continuous.py).
 
 Equivalence contracts pinned here:
 * LPT-packed chunks yield bitwise-identical params / steps-to-constraint to
@@ -7,8 +7,6 @@ Equivalence contracts pinned here:
 * serial, vmap, and shard_map engines produce identical resilience tables
   and steps-to-constraint (the shard_map check runs in-process on whatever
   devices exist, and in a subprocess on a forced 8-host-device CPU mesh).
-* FleetServeEngine greedy generation reproduces per-chip ServeEngine
-  token-for-token.
 """
 import json
 import os
@@ -19,19 +17,16 @@ import jax
 import numpy as np
 import pytest
 
-from repro.configs import get_arch, reduce_config
-from repro.core import EFAT, EFATConfig, from_fault_map, healthy, random_fault_map
+from repro.configs import get_arch
+from repro.core import EFAT, EFATConfig, random_fault_map
 from repro.core.resilience import measure_resilience
 from repro.fleet import (
     FleetScheduler,
-    FleetServeEngine,
     ShardedPopulationEngine,
     suggest_population_size,
 )
 from repro.fleet.capacity import BYTES_PER_PARAM
 from repro.launch.mesh import make_fleet_mesh, make_pop_mesh
-from repro.models import model as M
-from repro.serve.engine import ServeEngine
 from repro.train.fat_trainer import ClassifierFATTrainer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -459,73 +454,3 @@ def test_serial_vmap_shardmap_identical_on_8_device_mesh():
     assert res["tp_per_member_resident_bytes"] <= (
         res["per_member_total_bytes"] / 2 * 1.05 + 1024
     ), res
-
-
-# ---------------------------------------------------------------------------
-# FleetServeEngine
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def serve_fleet():
-    cfg = reduce_config(get_arch("smollm-135m"))
-    key = jax.random.PRNGKey(0)
-    chips = []
-    for i, rate in enumerate((0.0, 0.25, 0.4)):
-        params, _ = M.init_params(cfg, jax.random.PRNGKey(i))
-        ctx = (
-            healthy()
-            if rate == 0.0
-            else from_fault_map(random_fault_map(i, cfg.array_rows, cfg.array_cols, rate))
-        )
-        chips.append((params, ctx))
-    prompts = jax.random.randint(key, (2, 8), 0, cfg.vocab_size)
-    return cfg, chips, prompts
-
-
-def test_fleet_serve_greedy_matches_per_chip_engines(serve_fleet):
-    cfg, chips, prompts = serve_fleet
-    fleet_eng = FleetServeEngine(
-        cfg, [p for p, _ in chips], [c for _, c in chips], max_len=48
-    )
-    out = fleet_eng.generate(prompts, max_new_tokens=6)
-    assert out.tokens.shape == (len(chips), 2, 8 + 6)
-    assert out.logprobs.shape == (len(chips), 2, 6)
-    for i, (params, ctx) in enumerate(chips):
-        ref = ServeEngine(cfg, params, ctx, max_len=48).generate(prompts, max_new_tokens=6)
-        toks_i, lps_i = out.chip(i)
-        assert np.array_equal(np.asarray(toks_i), np.asarray(ref.tokens)), f"chip {i}"
-        np.testing.assert_allclose(
-            np.asarray(lps_i), np.asarray(ref.logprobs), rtol=1e-5, atol=1e-5
-        )
-
-
-def test_fleet_serve_faulty_chips_diverge(serve_fleet):
-    """Chips share prompts but not weights/masks — generations must differ
-    across chips, proving each lane runs its own (params, mask)."""
-    cfg, chips, prompts = serve_fleet
-    params0, _ = chips[0]
-    ctxs = [c for _, c in chips]
-    eng = FleetServeEngine(cfg, [params0] * 3, ctxs, max_len=48)
-    out = eng.generate(prompts, max_new_tokens=6)
-    gen = np.asarray(out.tokens[:, :, 8:])
-    assert not np.array_equal(gen[0], gen[1])  # healthy vs faulty mask
-
-
-def test_fleet_serve_temperature_uses_per_chip_keys(serve_fleet):
-    cfg, chips, prompts = serve_fleet
-    params0, _ = chips[0]
-    eng = FleetServeEngine(cfg, [params0] * 2, None, max_len=48)
-    out = eng.generate(
-        prompts, max_new_tokens=6, temperature=1.0, key=jax.random.PRNGKey(3)
-    )
-    # same params + healthy ctx, different per-chip sample streams
-    assert not np.array_equal(np.asarray(out.tokens[0]), np.asarray(out.tokens[1]))
-
-
-def test_fleet_serve_validates_inputs(serve_fleet):
-    cfg, chips, _ = serve_fleet
-    with pytest.raises(ValueError):
-        FleetServeEngine(cfg, [], [])
-    with pytest.raises(ValueError):
-        FleetServeEngine(cfg, [chips[0][0]], [healthy(), healthy()])

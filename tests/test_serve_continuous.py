@@ -11,7 +11,9 @@ Contracts pinned here:
 * static (EOS-masked) and continuous engines agree on EOS semantics;
 * ShardedFleetServeEngine serves N chips' independent ragged streams with
   per-chip outputs identical to per-chip ContinuousBatchingEngine runs, and
-  per-chip temperature sampling is reproducible and chip-independent.
+  per-chip temperature sampling is reproducible and chip-independent; each
+  chip's served weights are a single engine's premask under its context,
+  and after warmup() nothing compiles while it serves.
 """
 import jax
 import jax.numpy as jnp
@@ -683,3 +685,90 @@ def test_fleet_sharded_serve_validates(fleet):
     eng = ShardedFleetServeEngine(cfg, [p for p, _ in chips[:2]], num_slots=1)
     with pytest.raises(ValueError, match="streams"):
         eng.serve([streams[0]])
+
+
+def test_fleet_chips_sharing_params_diverge_by_fault_map(fleet):
+    """Chips that share weights and a stream but not a fault map serve
+    different tokens: each chip runs its own premasked weights."""
+    cfg, chips, streams = fleet
+    params0 = chips[0][0]
+    eng = ShardedFleetServeEngine(
+        cfg, [params0] * 3, [c for _, c in chips[:3]], num_slots=2, page_size=4,
+        num_pages=32,
+    )
+    outs, _ = eng.serve([streams[0]] * 3)
+    gen = [np.concatenate([outs[c][rid].tokens for rid in sorted(outs[c])]) for c in range(3)]
+    assert not np.array_equal(gen[0], gen[1])  # healthy vs faulty mask
+    assert not np.array_equal(gen[1], gen[2])  # two faulty masks
+
+
+def test_fleet_served_weights_are_each_chips_premask(fleet):
+    """Row c of the fleet's served weights is what a single engine serves
+    under chip c's context, and set_silicon(c, ctx) rewrites row c only."""
+    cfg, chips, _ = fleet
+    R, C = cfg.array_rows, cfg.array_cols
+    pristine = from_fault_map(random_fault_map(0, R, C, 0.0))
+    ctxs = [pristine] + [c for _, c in chips[1:]]
+    params = [p for p, _ in chips]
+    kw = dict(num_slots=2, page_size=4, num_pages=32)
+    eng = ShardedFleetServeEngine(cfg, params, ctxs, **kw)
+
+    def assert_row(stack, c, want):
+        jax.tree_util.tree_map(
+            lambda s, w: np.testing.assert_array_equal(np.asarray(s)[c], np.asarray(w)),
+            stack, want)
+
+    for c, ctx in enumerate(ctxs):
+        assert_row(eng.served_params, c,
+                   ContinuousBatchingEngine(cfg, params[c], ctx, **kw).served_params)
+    before = jax.tree_util.tree_map(np.asarray, eng.served_params)
+    new = from_fault_map(random_fault_map(7, R, C, 0.3))
+    eng.set_silicon(1, new)
+    assert eng.ctxs[1] is new
+    assert_row(eng.served_params, 1, ContinuousBatchingEngine(cfg, params[1], new, **kw).served_params)
+    for c in (0, 2, 3):
+        assert_row(eng.served_params, c, jax.tree_util.tree_map(lambda x: x[c], before))
+    assert any(not np.array_equal(np.asarray(a)[1], b[1]) for a, b in zip(
+        jax.tree_util.tree_leaves(eng.served_params), jax.tree_util.tree_leaves(before)))
+
+
+def test_fleet_warmup_serves_without_compiling(fleet):
+    """After warmup() the fleet serves the fixture streams through its AOT
+    programs only — one per bucket, the chunk and the decode program — and,
+    its weights premasked, none of them holds a fault-mask op."""
+    cfg, chips, streams = fleet
+    eng = ShardedFleetServeEngine(
+        cfg, [p for p, _ in chips], [c for _, c in chips], num_slots=2, page_size=4,
+        num_pages=32, prefill_buckets=(8, 16),
+    )
+    assert eng.warmup() == 4
+    outs, _ = eng.serve(streams)
+    assert [len(o) for o in outs] == [3] * 4
+    cc = eng.compile_counts()
+    assert cc["jit_fallback"] == 0 and cc["aot"] == 4
+    assert eng.mask_ops and all(ops == [] for ops in eng.mask_ops.values())
+
+
+def test_fleet_rounds_are_recorded_on_per_chip_tracks(fleet):
+    """The fleet runs the single engine's round loop: its rounds and their
+    stages are spans on the fleet's host track, and each chip's requests and
+    page pool sit on that chip's own tracks."""
+    from repro.obs import Recorder
+
+    cfg, chips, streams = fleet
+    rec = Recorder()
+    eng = ShardedFleetServeEngine(
+        cfg, [p for p, _ in chips], [c for _, c in chips], num_slots=2, page_size=4,
+        num_pages=32, recorder=rec,
+    )
+    outs, stats = eng.serve(streams)
+    evs = [e for e in rec.event_list() if e.proc == "fleet"]
+    host = {e.name for e in evs if e.track == "host" and e.kind == "span"}
+    assert {"serve_round", "schedule", "decode.dispatch", "decode.wait",
+            "decode.fetch", "record_step"} <= host
+    rounds = [e for e in evs if e.name == "serve_round"]
+    assert len(rounds) >= stats.decode_dispatches
+    for c in range(len(chips)):
+        retired = [e for e in evs if e.name == "retire" and e.track.startswith(f"chip{c}/slot")]
+        assert sorted(e.args["rid"] for e in retired) == sorted(outs[c])
+        assert any(e.track == f"chip{c}/pages" for e in evs)
